@@ -14,10 +14,10 @@ Usage:
       [--out results/superkernel_tuning.json] [--buckets 8,16,32]
 
 Serve with the result via `serve.py --tuning-table <path>` or
-`ASAP_TUNING_TABLE=<path>`.  Timings are interpret-mode on CPU in this
-container — the sweep HARNESS is the deliverable; re-run on real TPU to
-re-baseline (the table carries `meta.platform` so a mismatched table is
-visible in provenance).
+`ASAP_TUNING_TABLE=<path>`.  The kernels run compiled on a TPU and in the
+Pallas interpreter on the CPU (`repro.kernels.interpret`); the table records
+`meta.platform`, `meta.device_kind` and `meta.interpret`, and a TPU run
+refuses a table that was not swept on the same TPU kind.
 """
 from __future__ import annotations
 
@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import fmt_table
+from repro.kernels.interpret import resolve_interpret
 from repro.kernels.super_gmm import tuning
 from repro.kernels.super_gmm.super_gmm import super_gmm
 
@@ -51,8 +52,7 @@ def _time_blocking(lid, w, xb, blocks, reps: int) -> float:
     call."""
     bc, bn, bk = blocks
     def launch():
-        return super_gmm(lid, w, xb, block_c=bc, block_n=bn, block_k=bk,
-                         interpret=True)
+        return super_gmm(lid, w, xb, block_c=bc, block_n=bn, block_k=bk)
     launch().block_until_ready()  # compile + warm
     best = float("inf")
     for _ in range(reps):
@@ -84,8 +84,10 @@ def run(quick: bool = False, buckets=None, out: str = OUT) -> dict:
     limit = 6 if quick else 12
     reps = 2 if quick else 3
 
+    dev = jax.devices()[0]
     table = tuning.TuningTable(meta=dict(
-        platform=jax.devices()[0].platform, interpret=True,
+        platform=dev.platform, device_kind=dev.device_kind,
+        interpret=resolve_interpret(),
         buckets=list(buckets), candidates_per_gmm=limit))
     rows = []
     for g in geos:

@@ -106,7 +106,7 @@ def diff_metrics(golden: Dict[str, float], measured: Dict[str, float],
 
 def _make_mesh():
     import jax
-    from repro.launch.mesh import _axis_type_kwargs
+    from jax.sharding import AxisType
     n = len(jax.devices())
     need = MESH_SHAPE[0] * MESH_SHAPE[1]
     if n < need:
@@ -116,7 +116,7 @@ def _make_mesh():
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need} "
             f"before jax is imported)")
     return jax.make_mesh(MESH_SHAPE, MESH_AXES,
-                         **_axis_type_kwargs(len(MESH_AXES)))
+                         axis_types=(AxisType.Auto,) * len(MESH_AXES))
 
 
 def measure(spec: ContractSpec, mesh=None) -> Dict[str, float]:
@@ -127,7 +127,6 @@ def measure(spec: ContractSpec, mesh=None) -> Dict[str, float]:
     from repro.configs import get_config
     from repro.launch import sharding as SH
     from repro.launch.hlo_analysis import analyze
-    from repro.launch.mesh import jit_shardings, mesh_context
     from repro.launch.steps import TrainState, build_train_step
     from repro.models.api import build_api
     from repro.optim.adamw import AdamW
@@ -160,9 +159,8 @@ def measure(spec: ContractSpec, mesh=None) -> Dict[str, float]:
         args, in_sh = (params_sds, batch_sds), (pspecs, bspecs)
     else:
         raise ValueError(f"unknown contract kind {spec.kind!r}")
-    with mesh_context(mesh):
-        compiled = jax.jit(
-            fn, in_shardings=jit_shardings(mesh, in_sh)).lower(*args).compile()
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(fn, in_shardings=in_sh).lower(*args).compile()
         hlo = compiled.as_text()
     hc = analyze(hlo)
     return {

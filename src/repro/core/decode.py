@@ -33,7 +33,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -117,6 +117,37 @@ class SimDecodeEngine:
 # ---------------------------------------------------------------------------
 
 
+def make_decode_step(cfg: ModelConfig,
+                     on_trace: Callable[[], None] = lambda: None):
+    """ONE jitted single-token decode step over ragged KV slots:
+    `step(params, k, v, tokens, lengths, active)` embeds the last sampled
+    ids, `lax.scan`s the stacked decoder layers through
+    `decoder_block_decode_ragged` (per-row cache append + ragged mask),
+    takes the lm_head argmax and freezes inactive rows.  The params are an
+    ARGUMENT (a closure would bake every weight into the HLO); `on_trace`
+    runs at trace time only — the retrace probe."""
+    moe = cfg.family == "moe"
+
+    def step(params, k, v, tokens, lengths, active):
+        on_trace()
+        h = embed_tokens(params, tokens[:, None], None, cfg)
+
+        def body(hh, xs):
+            lp, kc, vc = xs
+            hh, ck, cv = decoder_block_decode_ragged(
+                lp, hh, kc, vc, lengths, cfg, moe=moe)
+            return hh, (ck, cv)
+
+        h, (nk, nv) = jax.lax.scan(body, h, (params["stages"][0], k, v))
+        hN = apply_norm(h[:, 0], params["final_norm"], cfg)
+        nxt = jnp.argmax(lm_head(params, hN, cfg), -1).astype(jnp.int32)
+        new_tokens = jnp.where(active, nxt, tokens)
+        new_lengths = jnp.where(active, lengths + 1, lengths)
+        return nk, nv, new_tokens, new_lengths
+
+    return jax.jit(step)
+
+
 class DecodeExecutor:
     """Jitted continuous-batching decode runtime over preallocated ragged
     KV slots.
@@ -154,33 +185,18 @@ class DecodeExecutor:
         self._active = np.zeros((slots,), bool)  # host mirror of occupancy
         self.trace_counts: Dict[str, int] = {"decode_step": 0}
         self._trace_lock = threading.Lock()
-        self._step = self._make_step()
+        self._step = make_decode_step(cfg, on_trace=self._count_trace)
 
-    def _make_step(self):
-        cfg = self.cfg
-        sp = self.params["stages"][0]
-        moe = cfg.family == "moe"
+    def _count_trace(self):
+        with self._trace_lock:  # runs at trace time only (retrace probe)
+            self.trace_counts["decode_step"] += 1
 
-        def step(k, v, tokens, lengths, active):
-            with self._trace_lock:  # runs at trace time only (retrace probe)
-                self.trace_counts["decode_step"] += 1
-            h = embed_tokens(self.params, tokens[:, None], None, cfg)
-
-            def body(hh, xs):
-                lp, kc, vc = xs
-                hh, ck, cv = decoder_block_decode_ragged(
-                    lp, hh, kc, vc, lengths, cfg, moe=moe)
-                return hh, (ck, cv)
-
-            h, (nk, nv) = jax.lax.scan(body, h, (sp, k, v))
-            hN = apply_norm(h[:, 0], self.params["final_norm"], cfg)
-            nxt = jnp.argmax(lm_head(self.params, hN, cfg), -1) \
-                .astype(jnp.int32)
-            new_tokens = jnp.where(active, nxt, tokens)
-            new_lengths = jnp.where(active, lengths + 1, lengths)
-            return nk, nv, new_tokens, new_lengths
-
-        return jax.jit(step)
+    def prewarm(self):
+        """Compile the decode step before serving: one step over the empty
+        slots (no row is active, so the state is left as it was)."""
+        jax.block_until_ready(self._step(
+            self.params, self._k, self._v, self._tokens, self._lengths,
+            jnp.asarray(self._active)))
 
     def occupy(self, slot: int, handle: KVHandle, first_token: int):
         """Enroll one request into `slot`: device move of its prefill KV
@@ -204,7 +220,7 @@ class DecodeExecutor:
     def step_once(self) -> Tuple[float, np.ndarray]:
         """One batched decode step; returns (t_done, per-slot token ids)."""
         self._k, self._v, self._tokens, self._lengths = self._step(
-            self._k, self._v, self._tokens, self._lengths,
+            self.params, self._k, self._v, self._tokens, self._lengths,
             jnp.asarray(self._active))
         toks = np.asarray(self._tokens)
         return self.clock(), toks

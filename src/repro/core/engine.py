@@ -174,7 +174,10 @@ class EngineStats:
     # live placement-control accounting (ISSUE 5)
     placement_policy: Optional[str] = None  # currently installed placement
     migrations: int = 0  # MigrationPlans executed so far
-    migrated_bytes: float = 0.0  # expert weight bytes shipped by them
+    # expert weight bytes the plans move between devices in the sim; the
+    # executor's MoE devices share one chip and swap expert ids, so there
+    # it is the bytes a cross-chip placement would move (nothing is copied)
+    migrated_bytes: float = 0.0
     # fault tolerance (ISSUE 8)
     failovers: int = 0  # supervised MoE-device evacuations executed
     statuses: Optional[Dict[str, int]] = None  # terminal status histogram
@@ -942,8 +945,8 @@ class ExecutorEngine(ServingEngine):
         `rebalance_interval` trace seconds, hand the controller the window's
         MEASURED observations (per-device busy time, per-expert routing
         fractions) and execute the MigrationPlan it emits — quiesce the
-        affected MoE devices, copy the moved experts' weight slices, swap
-        the dispatch tables, and retarget the batcher's inflection for the
+        affected MoE devices, swap their expert ids and the dispatch
+        tables, and retarget the batcher's inflection for the
         new hot fraction."""
         c = self.controller
         if c is None or not c.active or self._stop.is_set():

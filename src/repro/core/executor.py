@@ -10,7 +10,7 @@ core/async_primitives.py. Every mechanism of the paper is present:
   * dual-batch interleaving on attention devices (§3.3.2)
   * out-of-order MoE: devices block in `wait_any` and process whichever DP
     group's batch-layer completes first — the layer id arrives as DATA
-    (metadata ①) and indexes the resident [L, n_e, ...] weight stack exactly
+    (metadata ①) and indexes the stacked [L, n, ...] expert weights exactly
     like the MoE Super Kernel's scalar-prefetch index (§3.4.2)
   * shared-expert compute on the attention device overlapped with the routed
     experts' remote execution (beyond-paper overlap; disable with
@@ -21,10 +21,10 @@ core/async_primitives.py. Every mechanism of the paper is present:
     routed to its least-loaded replica — the same placement tables that
     drive the simulator's `ExpertLoadModel` (ROADMAP item d).
   * LIVE expert re-placement (ISSUE 5, ROADMAP d3): `apply_placement`
-    swaps the resident weight stacks + dispatch tables mid-serve — freeze
-    the dispatch gate, quiesce the affected MoE devices, copy the moved
-    experts' [L, ...] weight slices, swap atomically.  Driven between polls
-    by the `PlacementController` via `core.engine.ExecutorEngine`.
+    swaps the devices' expert-id vectors + dispatch tables mid-serve —
+    freeze the dispatch gate, quiesce the affected MoE devices, swap
+    atomically.  Driven between polls by the `PlacementController` via
+    `core.engine.ExecutorEngine`.
   * jitted combine (ROADMAP item i): the per-batch-layer weighted
     accumulation of expert outputs is ONE scatter-add jit
     (`combine_path="segsum"`); the np.add.at host loop survives as
@@ -43,12 +43,19 @@ Hot path (`moe_path="fused"`, the default — §3.4.2 made real):
     capacity buffers ([n_e, C, d]; C bucketed to powers of two so the jit
     cache stays finite) by `kernels.super_gmm.ops.pack_capacity` — a
     vectorized segment-sort/scatter — then ONE jitted `super_moe_ffn` call
-    runs all three expert projections against the device's resident
-    [L, n_e, ...] weight stack with the layer id as a runtime scalar: the
-    layer-oblivious super-kernel semantics (global weight access +
-    pre-calculated indexing + dynamic resolution), not an eager per-expert
-    Python loop.  `moe_path="eager"` keeps the pre-fusion per-expert loop as
-    the benchmark baseline (benchmarks/fig_executor_hotpath.py).
+    runs all three expert projections against the model's stacked
+    [L, n, ...] expert weights, with the layer id and the device's expert
+    ids as runtime data: the layer-oblivious super-kernel semantics (global
+    weight access + pre-calculated indexing + dynamic resolution), not an
+    eager per-expert Python loop.  `moe_path="eager"` keeps the pre-fusion
+    per-expert loop as the benchmark baseline
+    (benchmarks/fig_executor_hotpath.py).
+
+Weights are jit ARGUMENTS, never closed-over constants: a closure would
+embed them in the HLO (gigabytes at published widths).  The expert weights
+live on the device once — in `params` — and each MoE device addresses its
+experts there through a small id vector, so a live re-placement swaps ids,
+not weight copies.
 
 Numerical contract (tested): pipeline output == lm_backbone(..., moe_mode=
 "dense") for the same params — asynchrony, placement and fusion must not
@@ -71,9 +78,10 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -92,6 +100,67 @@ from repro.models.attention import attention_forward, attention_prefill
 from repro.models.common import ModelConfig, act_fn, apply_norm
 from repro.models.moe import gated_ffn, router_topk
 from repro.models.lm import embed_tokens, lm_stages
+
+
+def make_attn_step(cfg: ModelConfig, *, emit_kv: bool = False,
+                   shared: bool = False,
+                   on_trace: Callable[[], None] = lambda: None):
+    """One jitted attention+norm+router(+shared) step for ALL layers:
+    `step(sp, lid, h)` takes the stacked per-layer params `sp` (keys attn,
+    ln_attn, ln_ffn, router and, with `shared`, shared) as an ARGUMENT and
+    the layer id as a traced scalar that indexes them, so the steady state
+    performs zero retraces (jax.jit keys on shapes only).  `on_trace` runs
+    at trace time only — the executor's retrace probe.
+
+    With `emit_kv` (ISSUE 9) the attention part runs through
+    `attention_prefill` and the step ALSO returns the layer's (k, v) cache —
+    the raw material of the prefill->decode KV handoff.  Both flags are
+    Python-level, so the jit cache still keys on shapes only."""
+
+    def step(sp, lid, h):
+        on_trace()
+        lp = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, lid, 0, keepdims=False),
+            sp)
+        kv = None
+        if emit_kv:
+            a, cache = attention_prefill(
+                lp["attn"], apply_norm(h, lp["ln_attn"], cfg), cfg,
+                use_dense=True)
+            h = h + a
+            kv = (cache.k, cache.v)
+        else:
+            h = h + attention_forward(lp["attn"],
+                                      apply_norm(h, lp["ln_attn"], cfg),
+                                      cfg, use_dense=True)
+        x = apply_norm(h, lp["ln_ffn"], cfg)
+        B, S, d = x.shape
+        xf = x.reshape(B * S, d)
+        weights, idx, _ = router_topk(lp["router"], xf, cfg)
+        out_shared = None
+        if shared:
+            s = lp["shared"]
+            out_shared = gated_ffn(xf, s["w_gate"], s["w_up"], s["w_down"],
+                                   act_fn(cfg.act))
+        return h, xf, weights, idx, out_shared, kv
+
+    return jax.jit(step)
+
+
+def make_moe_step(cfg: ModelConfig, *, kernel: str = "pallas",
+                  on_trace: Callable[[], None] = lambda: None):
+    """Jitted super-kernel FFN `step(experts, ids, lid, xb)`: the stacked
+    [L, n, ...] expert weights are an ARGUMENT, `ids` [n_e] names the
+    experts whose capacity buffers `xb` [n_e, C, d] holds, and the layer id
+    is a runtime [1] scalar — ONE trace serves every layer and every device
+    with the same n_e; new traces only occur for new capacity buckets."""
+
+    def step(experts, ids, lid, xb):
+        on_trace()
+        return super_moe_ffn(lid, experts, xb, cfg, expert_ids=ids,
+                             kernel=kernel)
+
+    return jax.jit(step)
 
 
 @dataclasses.dataclass
@@ -196,20 +265,17 @@ class DisaggregatedExecutor:
         self.moe_bufs = [MoEDeviceBuffer(D, T) for _ in range(E)]
         self.attn_bufs = [[AttnDeviceBuffer(E) for _ in range(2)]
                           for _ in range(D)]  # per group x dual-batch slot
-        # "resident" expert weights per MoE device: [L, n_e, ...] — the
-        # super-kernel layout (all layers resident; layer id indexes at
-        # runtime).  n_e follows the placement: replicas are resident on
-        # every host.  The full host-side stacks stay addressable in
-        # `_experts_np` — they are the migration source a live re-placement
-        # copies moved experts' weight slices from (ISSUE 5).
-        ex = self.stage["ffn"]["experts"]
-        self._experts_np = {k: np.asarray(v) for k, v in ex.items()}
-        self.resident = [self._resident_stack(self.dev_experts[e])
+        # expert weights: ONE [L, n, ...] stack on the device (the params'
+        # own); MoE device e serves the experts listed in `_moe_ids[e]`
+        # (all layers resident; layer id and expert ids index at runtime).
+        # Replicas are listed on every host.
+        self._experts = self.stage["ffn"]["experts"]
+        self._moe_ids = [self._expert_ids(self.dev_experts[e])
                          for e in range(E)]
         # --- live re-placement state (ISSUE 5) ----------------------------
         # dispatch gate: apply_placement freezes new dispatches (readers of
         # the routing tables) and waits for in-flight ones to drain before
-        # swapping tables + resident stacks; `_moe_active[e]` marks a device
+        # swapping tables + expert ids; `_moe_active[e]` marks a device
         # mid-region (set BEFORE dispatch_recv clears the flags, so
         # "no flags set and not active" really means quiescent)
         self._gate_cv = threading.Condition()
@@ -274,10 +340,13 @@ class DisaggregatedExecutor:
                             "router": self.stage["ffn"]["router"]}
         if "shared" in self.stage["ffn"] and shared_on_attention:
             self._attn_stage["shared"] = self.stage["ffn"]["shared"]
-        self._attn_step = self._make_attn_step()
+        self._attn_jit = make_attn_step(
+            cfg, emit_kv=emit_kv, shared="shared" in self._attn_stage,
+            on_trace=functools.partial(self._count_trace, "attn"))
         self._combine_step = self._make_combine_step()
-        self._moe_step = [self._make_moe_step(e) if len(self.dev_experts[e])
-                          else None for e in range(E)]
+        self._moe_jit = make_moe_step(
+            cfg, kernel=moe_kernel,
+            on_trace=functools.partial(self._count_trace, "moe"))
         self.stop = threading.Event()
         self.errors: List[BaseException] = []
         # event log for protocol assertions in tests
@@ -332,6 +401,10 @@ class DisaggregatedExecutor:
         with self._log_lock:
             self.log.append(ev)
 
+    def _count_trace(self, name: str):
+        with self._trace_lock:  # jit tracing may run on several threads
+            self.trace_counts[name] += 1
+
     # ------------------------------------------------- placement derivation
     def _dispatch_lookups(self, table, dev_experts):
         """(primary, replicated, g2l) routing lookups for a placement table
@@ -344,67 +417,27 @@ class DisaggregatedExecutor:
             g2l[e, list(held)] = np.arange(len(held))
         return primary, replicated, g2l
 
-    def _resident_stack(self, held) -> Dict[str, np.ndarray]:
-        """One device's resident [L, n_e, ...] weight stack, sliced from the
-        host-side master copies."""
-        ids = np.asarray(held, np.int64)
-        return {k: v[:, ids] for k, v in self._experts_np.items()}
+    @staticmethod
+    def _expert_ids(held) -> Optional[jax.Array]:
+        """Device-side [n_e] int32 ids of the experts one MoE device serves
+        (None for a device that holds none)."""
+        return jnp.asarray(held, jnp.int32) if len(held) else None
 
     @property
     def expert_copy_bytes(self) -> float:
         """Bytes of ONE expert's weights for ONE layer — the per-copy unit
         the placement controller prices MigrationPlans in."""
-        return float(sum(v[0, 0].nbytes for v in self._experts_np.values()))
+        return float(sum(v.dtype.itemsize * int(np.prod(v.shape[2:]))
+                         for v in self._experts.values()))
 
     # ------------------------------------------------------------ attention
     def _layer_params(self, l: int):
         return jax.tree.map(lambda a: a[l], self.stage)
 
-    def _make_attn_step(self):
-        """One jitted attention+norm+router(+shared) step for ALL layers:
-        the layer id is a traced scalar indexing the stacked params, so the
-        steady state performs zero retraces (jax.jit keys on shapes only).
-        The stacked params are closed over (resident, like the MoE steps'
-        weights) so per-call dispatch doesn't re-flatten the pytree.
-
-        With `emit_kv` (ISSUE 9) the attention part runs through
-        `attention_prefill` and the step ALSO returns the layer's (k, v)
-        cache — the raw material of the prefill->decode KV handoff.  The
-        branch is Python-level on a constructor flag, so the jit cache
-        still keys on shapes only."""
-        cfg = self.cfg
-        sp = self._attn_stage
-        emit_kv = self.emit_kv
-
-        def step(lid, h):
-            with self._trace_lock:  # runs at trace time only
-                self.trace_counts["attn"] += 1
-            lp = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, lid, 0,
-                                                       keepdims=False), sp)
-            kv = None
-            if emit_kv:
-                a, cache = attention_prefill(
-                    lp["attn"], apply_norm(h, lp["ln_attn"], cfg), cfg,
-                    use_dense=True)
-                h = h + a
-                kv = (cache.k, cache.v)
-            else:
-                h = h + attention_forward(lp["attn"],
-                                          apply_norm(h, lp["ln_attn"], cfg),
-                                          cfg, use_dense=True)
-            x = apply_norm(h, lp["ln_ffn"], cfg)
-            B, S, d = x.shape
-            xf = x.reshape(B * S, d)
-            weights, idx, _ = router_topk(lp["router"], xf, cfg)
-            shared = None
-            if "shared" in sp:
-                s = lp["shared"]
-                shared = gated_ffn(xf, s["w_gate"], s["w_up"], s["w_down"],
-                                   act_fn(cfg.act))
-            return h, xf, weights, idx, shared, kv
-
-        return jax.jit(step)
+    def _attn_step(self, lid, h):
+        """The fused attention step (see `make_attn_step`) on this
+        executor's stacked attention params."""
+        return self._attn_jit(self._attn_stage, lid, h)
 
     def _attn_part(self, lp, h):
         """Eager (pre-fusion) attention step — the `moe_path="eager"`
@@ -549,15 +582,13 @@ class DisaggregatedExecutor:
         batch-layer, so the jit cache stays keyed on the batch buckets
         already in play (no new retrace churn); scatter rows keep payload
         order, which keeps the accumulation bit-identical to the host path
-        (pinned in tests/test_executor.py)."""
+        (pinned in tests/test_executor.py).  Both paths start the
+        accumulator from the shared-expert output (or zeros), so neither
+        leaves the compiler a trailing add to reorder."""
 
-        def step(acc0, outs, t, w, shared):
-            with self._trace_lock:  # runs at trace time only
-                self.trace_counts["combine"] += 1
-            acc = acc0.at[t].add(outs * w[:, None])
-            if shared is not None:
-                acc = acc + shared.astype(jnp.float32)
-            return acc
+        def step(acc0, outs, t, w):
+            self._count_trace("combine")
+            return acc0.at[t].add(outs * w[:, None])
 
         return jax.jit(step)
 
@@ -578,7 +609,8 @@ class DisaggregatedExecutor:
         Tn, d = xf.shape
         layer = None
         if self.combine_path == "host":
-            acc = np.zeros((Tn, d), np.float32)
+            acc = np.zeros((Tn, d), np.float32) if shared is None \
+                else np.array(shared, np.float32)
             for p in payloads:
                 if p.outputs is None or len(p.token_ids) == 0:
                     continue
@@ -587,8 +619,6 @@ class DisaggregatedExecutor:
                 k = p.token_ids[:, 1]
                 w = weights[t, k][:, None]
                 np.add.at(acc, t, np.asarray(p.outputs, np.float32) * w)
-            if shared is not None:
-                acc = acc + np.asarray(shared, np.float32)
         else:
             outs, ts, ws = [], [], []
             for p in payloads:
@@ -599,37 +629,26 @@ class DisaggregatedExecutor:
                 outs.append(np.asarray(p.outputs, np.float32))
                 ts.append(t)
                 ws.append(weights[t, p.token_ids[:, 1]])
+            acc0 = jnp.zeros((Tn, d), jnp.float32) if shared is None \
+                else shared.astype(jnp.float32)
             if outs:
                 acc = np.asarray(self._combine_step(
-                    jnp.zeros((Tn, d), jnp.float32),
-                    jnp.asarray(np.concatenate(outs, 0)),
+                    acc0, jnp.asarray(np.concatenate(outs, 0)),
                     jnp.asarray(np.concatenate(ts, 0)),
-                    jnp.asarray(np.concatenate(ws, 0).astype(np.float32)),
-                    shared))
+                    jnp.asarray(np.concatenate(ws, 0).astype(np.float32))))
             else:
-                acc = np.zeros((Tn, d), np.float32)
-                if shared is not None:
-                    acc = acc + np.asarray(shared, np.float32)
+                acc = np.asarray(acc0)
         B, S, _ = h.shape
         y = jnp.asarray(acc.astype(np.float32)).astype(h.dtype)
         self._logev("combine", g, slot, layer)
         return h + y.reshape(B, S, d)
 
     # ----------------------------------------------------------- moe worker
-    def _make_moe_step(self, e: int):
-        """Jitted super-kernel FFN for device e: the resident [L, n_e, ...]
-        stack is closed over (weights stay device-resident across calls) and
-        the layer id is a runtime [1] scalar — ONE trace serves every layer;
-        new traces only occur for new capacity buckets."""
-        res = {k: jnp.asarray(v) for k, v in self.resident[e].items()}
-        cfg, kernel = self.cfg, self.moe_kernel
-
-        def step(lid, xb):
-            with self._trace_lock:  # runs at trace time only
-                self.trace_counts["moe"] += 1
-            return super_moe_ffn(lid, res, xb, cfg, kernel=kernel)
-
-        return jax.jit(step)
+    def _moe_launch(self, e: int, layer: int, xb: np.ndarray) -> jax.Array:
+        """One super-kernel launch for device e: its capacity buffers
+        against the shared expert stack, indexed by its expert ids."""
+        return self._moe_jit(self._experts, self._moe_ids[e],
+                             jnp.asarray([layer], jnp.int32), jnp.asarray(xb))
 
     def prewarm_buckets(self, max_rows: int):
         """Trace the fused super-kernel for EVERY capacity bucket up to
@@ -643,17 +662,38 @@ class DisaggregatedExecutor:
         bucket_hits == launches in EngineStats."""
         assert self.moe_path == "fused", "prewarm traces the fused step"
         top = round_capacity(max(int(max_rows), 1))
-        lid = jnp.asarray([0], jnp.int32)
         for e in range(self.E):
-            if self._moe_step[e] is None:
+            if self._moe_ids[e] is None:
                 continue
             n_e = len(self.dev_experts[e])
             C = round_capacity(1)
             while C <= top:
-                xb = jnp.zeros((n_e, C, self.cfg.d_model), jnp.float32)
-                self._moe_step[e](lid, xb).block_until_ready()
+                # the serving dtype: payload rows are the attention step's
+                # normed hidden states, in the model dtype
+                xb = np.zeros((n_e, C, self.cfg.d_model), self.cfg.dtype)
+                self._moe_launch(e, 0, xb).block_until_ready()
                 self._seen_buckets[e].add(C)
                 C *= 2
+
+    def prewarm_batches(self, shapes: Sequence[tuple]):
+        """Compile the attention step and the jitted combine for every
+        (B, S) batch shape in `shapes` before serving, single-threaded — a
+        cold compile on a group thread mid-serve races `region_timeout`."""
+        assert self.moe_path == "fused", "prewarm traces the fused step"
+        lid = jnp.asarray(0, jnp.int32)
+        for B, S in shapes:
+            h = embed_tokens(self.params, jnp.zeros((B, S), jnp.int32), None,
+                             self.cfg)
+            _, xf, _, _, _, _ = self._attn_step(lid, h)
+            if self.combine_path == "segsum":
+                K = self.cfg.top_k
+                rows = B * S * K  # every (token, k) assignment combines
+                self._combine_step(
+                    jnp.zeros(xf.shape, jnp.float32),
+                    jnp.asarray(np.zeros((rows, xf.shape[1]), np.float32)),
+                    jnp.asarray(np.zeros((rows,), np.int64)),
+                    jnp.asarray(np.zeros((rows,), np.float32))
+                ).block_until_ready()
 
     def _record_launch(self, e: int, C: int, n_regions: int, n_rows: int):
         """Super-kernel launch telemetry (ISSUE 10).  Same ownership rule as
@@ -677,8 +717,7 @@ class DisaggregatedExecutor:
         n_e = len(self.dev_experts[e])
         xb, order, slots, C = pack_capacity(tokens, eids, n_e)
         self._record_launch(e, C, 1, len(tokens))
-        yb = self._moe_step[e](jnp.asarray([layer], jnp.int32),
-                               jnp.asarray(xb))
+        yb = self._moe_launch(e, layer, xb)
         return unpack_capacity(np.asarray(yb), order, slots, len(tokens))
 
     def _expert_ffn_fused_multi(self, e: int, layer: int, token_list,
@@ -692,25 +731,24 @@ class DisaggregatedExecutor:
         xb, order, slots, C, bounds = pack_capacity_multi(
             token_list, eid_list, n_e)
         self._record_launch(e, C, len(token_list), int(bounds[-1]))
-        yb = self._moe_step[e](jnp.asarray([layer], jnp.int32),
-                               jnp.asarray(xb))
+        yb = self._moe_launch(e, layer, xb)
         return unpack_capacity_multi(np.asarray(yb), order, slots, bounds)
 
     def _expert_ffn_eager(self, e: int, layer: int, tokens: np.ndarray,
                           eids: np.ndarray) -> np.ndarray:
         """Pre-fusion per-expert loop: three un-jitted GEMMs and a
         host<->device round trip per LOCAL expert (benchmark baseline)."""
-        res = self.resident[e]
+        held = self.dev_experts[e]
         act = act_fn(self.cfg.act)
-        wg, wu, wd = (res["w_gate"][layer], res["w_up"][layer],
-                      res["w_down"][layer])
+        w = self._experts
         out = np.zeros((len(tokens), tokens.shape[1]), np.float32)
         xj = jnp.asarray(tokens)
         for le in np.unique(eids):
             m = eids == le
             xm = xj[np.where(m)[0]]
-            y = (act(xm @ jnp.asarray(wg[le]))
-                 * (xm @ jnp.asarray(wu[le]))) @ jnp.asarray(wd[le])
+            g = held[int(le)]
+            y = (act(xm @ w["w_gate"][layer, g])
+                 * (xm @ w["w_up"][layer, g])) @ w["w_down"][layer, g]
             out[m] = np.asarray(y, np.float32)
         return out
 
@@ -1194,14 +1232,13 @@ class DisaggregatedExecutor:
              sends across two routing tables);
           2. quiesce the AFFECTED MoE devices: with no new dispatches, each
              one drains its buffered regions — payloads carry local expert
-             ids of the old tables and must be served by the old resident
-             stacks.  Unaffected devices keep serving throughout (their
+             ids of the old tables and must be served with the old expert
+             ids.  Unaffected devices keep serving throughout (their
              local id mapping is unchanged), and attention groups keep
              computing/combining — this is not a global barrier;
-          3. copy the moved experts' [L, ...] weight slices into the
-             receivers' new resident stacks (sourced from the host-side
-             master — the byte count accounted is exactly the new copies),
-             rebuild their jitted super-kernel steps;
+          3. hand the receivers their new expert-id vectors (on one chip
+             the weights stay where they are; the bytes accounted are the
+             expert copies a placement across chips would move);
           4. atomically swap `placement`/`table`/`dev_experts` + the dispatch
              lookups (`_primary`/`_replicated`/`_g2l`) and release the gate.
 
@@ -1223,8 +1260,8 @@ class DisaggregatedExecutor:
                                 kind: str = "rebalance") -> Dict[str, Any]:
         """apply_placement body; caller holds `_swap_lock`.  `drain_hook`
         (failover path) runs between drain polls OUTSIDE the gate cv: it
-        serves the dead device's buffered regions with the OLD resident
-        stack, which both empties them before the swap invalidates their
+        serves the dead device's buffered regions with the OLD expert
+        ids, which both empties them before the swap invalidates their
         local expert ids AND un-wedges any dispatcher blocked on the dead
         device's backpressure (that dispatcher holds the gate open)."""
         fr = tuple(float(x) for x in expert_fractions) \
@@ -1290,12 +1327,13 @@ class DisaggregatedExecutor:
                     if drain_hook is not None:
                         drain_hook()
                     time.sleep(0.001)
+            # bytes a cross-chip placement would move: every MoE device
+            # here reads the one shared expert stack, so nothing is copied
             nbytes = 0.0
             for e in affected:
                 gained = [x for x in new_dev[e]
                           if x not in self.dev_experts[e]]
                 nbytes += self.expert_copy_bytes * self.L * len(gained)
-                self.resident[e] = self._resident_stack(new_dev[e])
             # atomic swap: the gate is frozen and the affected devices are
             # idle, so no reader observes a mix of old and new tables
             self.placement, self.expert_fractions = placement, fr
@@ -1303,8 +1341,7 @@ class DisaggregatedExecutor:
             self._primary, self._replicated, self._g2l = \
                 self._dispatch_lookups(new_table, new_dev)
             for e in affected:
-                self._moe_step[e] = self._make_moe_step(e) \
-                    if len(new_dev[e]) else None
+                self._moe_ids[e] = self._expert_ids(new_dev[e])
         finally:
             with self._gate_cv:
                 self._gate_frozen = False
@@ -1315,8 +1352,8 @@ class DisaggregatedExecutor:
                "policy": placement.policy, "kind": kind}
         self.migrations.append(rec)
         self.migrated_bytes += nbytes
-        # the re-placement occupies the receiving devices (weight copy +
-        # jit rebuild); split the measured stall across them for stats()
+        # the re-placement occupies the receiving devices (quiesce + id
+        # swap); split the measured stall across them for stats()
         if affected:
             self.moe_busy[list(affected)] += dt / len(affected)  # race-ok: workers for `affected` are parked behind the frozen gate here
         self._logev("migrate", tuple(affected), len(moved))
@@ -1349,7 +1386,7 @@ class DisaggregatedExecutor:
 
     def _serve_region(self, e: int, i: int, rows) -> None:
         """Failover path: compute one orphaned region with device e's OLD
-        resident stack (on the supervisor thread) and combine it to its
+        expert ids (on the supervisor thread) and combine it to its
         group — unless the group already holds device e's segment (first
         combine wins: the worker may have sent before dying)."""
         layer = rows[0].layer

@@ -21,11 +21,14 @@ what tests pin down here.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.interpret import resolve_interpret
 
 
 def _scatter_kernel(token_of_ref, slot_ref, x_ref, init_ref, o_ref):
@@ -35,7 +38,8 @@ def _scatter_kernel(token_of_ref, slot_ref, x_ref, init_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("rows_out", "interpret"))
 def dispatch_scatter(token_of: jax.Array, slot: jax.Array, x: jax.Array, *,
-                     rows_out: int, interpret: bool = True) -> jax.Array:
+                     rows_out: int,
+                     interpret: Optional[bool] = None) -> jax.Array:
     """out[slot[i]] = x[token_of[i]] for i in range(N); out has rows_out rows
     (last row is the drop target and must be ignored by the caller).
 
@@ -56,7 +60,7 @@ def dispatch_scatter(token_of: jax.Array, slot: jax.Array, x: jax.Array, *,
         ),
         out_shape=jax.ShapeDtypeStruct((rows_out, d), x.dtype),
         input_output_aliases={3: 0},  # zero-init buffer donated to output
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(token_of, slot, x, init)
 
 
@@ -67,7 +71,7 @@ def _gather_kernel(slot_ref, y_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def combine_gather(slot: jax.Array, yb: jax.Array, *,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: Optional[bool] = None) -> jax.Array:
     """out[i] = yb[slot[i]]. slot: [N]; yb: [R, d] (row R-1 must be zeros —
     the drop target)."""
     N = slot.shape[0]
@@ -81,5 +85,5 @@ def combine_gather(slot: jax.Array, yb: jax.Array, *,
             out_specs=pl.BlockSpec((1, d), lambda i, slot: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((N, d), yb.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(slot, yb)

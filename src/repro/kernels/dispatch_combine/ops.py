@@ -7,9 +7,11 @@ done by the Pallas indirection kernels instead of jnp scatter/gather.
 Production-shape note: a row-per-pair grid issues N tiny DMAs; the production
 variant sorts slots so consecutive rows share destination blocks and copies
 8·128-aligned tiles (same index_map machinery, coarser grid). Kept simple here
-because the kernels run in interpret mode in this container.
+because the kernels are exercised only by the interpret-mode tests.
 """
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +23,7 @@ from repro.models.moe import expert_capacity
 
 
 def kernel_moe_dispatch(x: jax.Array, idx: jax.Array, cfg: ModelConfig,
-                        capacity=None, interpret: bool = True):
+                        capacity=None, interpret: Optional[bool] = None):
     """x: [T, d]; idx: [T, K] -> ([E, C, d], info) — same contract as
     models.moe.moe_dispatch."""
     T, d = x.shape
@@ -45,7 +47,7 @@ def kernel_moe_dispatch(x: jax.Array, idx: jax.Array, cfg: ModelConfig,
 
 
 def kernel_moe_combine(yb: jax.Array, info, weights: jax.Array, T: int,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: Optional[bool] = None) -> jax.Array:
     E, C, d = yb.shape
     K = weights.shape[1]
     flat = jnp.concatenate([yb.reshape(E * C, d),

@@ -20,7 +20,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.blocking import floor_to_divisor
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
+from repro.kernels.interpret import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -82,7 +82,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
                     softcap: Optional[float] = None, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True) -> jax.Array:
+                    block_k: int = 128,
+                    interpret: Optional[bool] = None) -> jax.Array:
     """q, k, v: [BH, S, dh] (kv already head-expanded). Returns [BH, S, dh]."""
     BH, S, dh = q.shape
     # round DOWN to a divisor (never min-clamp): S=192 with block 128 must
@@ -109,7 +110,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

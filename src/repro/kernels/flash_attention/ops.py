@@ -12,7 +12,8 @@ from repro.models.attention import _expand_kv
 def mha_flash(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, window: Optional[int] = None,
               softcap: Optional[float] = None, block_q: int = 128,
-              block_k: int = 128, interpret: bool = True) -> jax.Array:
+              block_k: int = 128,
+              interpret: Optional[bool] = None) -> jax.Array:
     """Model layout [B, S, H, dh] (kv may have fewer heads — GQA-expanded)."""
     B, S, H, dh = q.shape
     k = _expand_kv(k, H)

@@ -31,17 +31,21 @@ def _pick_blocks(C: int, N: int, K: int):
 
 
 def super_moe_ffn(layer_id: jax.Array, experts: dict, xb: jax.Array,
-                  cfg: ModelConfig, interpret: bool = True,
+                  cfg: ModelConfig, expert_ids: Optional[jax.Array] = None,
+                  interpret: Optional[bool] = None,
                   kernel: str = "pallas") -> jax.Array:
     """Gated expert FFN on capacity buffers via three super-GMM calls.
 
-    xb: [E, C, d] -> [E, C, d] (fp32).  kernel="ref" routes through the
-    layer-indexed einsum oracle instead of the Pallas grid — same layer-
-    oblivious semantics (layer id stays runtime data), useful where
-    interpret-mode Pallas is the bottleneck (CPU hot paths)."""
+    xb: [E, C, d] -> [E, C, d] (fp32); buffer e holds the rows of expert
+    `expert_ids[e]` of the stacked [L, n, ...] `experts` (default: expert
+    e).  kernel="ref" routes through the layer-indexed einsum oracle instead
+    of the Pallas grid — same layer-oblivious semantics (layer id stays
+    runtime data); a test/oracle option, never the chip path.  `interpret`
+    resolves through `repro.kernels.interpret`."""
     act = act_fn(cfg.act)
     if kernel == "ref":
-        return super_moe_ffn_ref(jnp.reshape(layer_id, ()), experts, xb, act)
+        return super_moe_ffn_ref(jnp.reshape(layer_id, ()), experts, xb, act,
+                                 expert_ids)
     E, C, d = xb.shape
     f = experts["w_gate"].shape[-1]
     # autotuned grid blocking when a table entry covers this geometry ×
@@ -53,17 +57,17 @@ def super_moe_ffn(layer_id: jax.Array, experts: dict, xb: jax.Array,
     else:
         bc, bn, bk = _pick_blocks(C, f, d)
         bc2, bn2, bk2 = _pick_blocks(C, d, f)
-    g = super_gmm(layer_id, experts["w_gate"], xb, block_c=bc, block_n=bn,
-                  block_k=bk, interpret=interpret)
-    u = super_gmm(layer_id, experts["w_up"], xb, block_c=bc, block_n=bn,
-                  block_k=bk, interpret=interpret)
+    g = super_gmm(layer_id, experts["w_gate"], xb, expert_ids, block_c=bc,
+                  block_n=bn, block_k=bk, interpret=interpret)
+    u = super_gmm(layer_id, experts["w_up"], xb, expert_ids, block_c=bc,
+                  block_n=bn, block_k=bk, interpret=interpret)
     h = (act(g) * u).astype(xb.dtype)
-    return super_gmm(layer_id, experts["w_down"], h, block_c=bc2, block_n=bn2,
-                     block_k=bk2, interpret=interpret)
+    return super_gmm(layer_id, experts["w_down"], h, expert_ids, block_c=bc2,
+                     block_n=bn2, block_k=bk2, interpret=interpret)
 
 
 def make_super_kernel_gmm(stacked_experts: dict, cfg: ModelConfig,
-                          interpret: bool = True) -> Callable:
+                          interpret: Optional[bool] = None) -> Callable:
     """Adapter for lm_forward(gmm=...): signature (xb, experts_layer, cfg,
     layer_id) -> yb. `experts_layer` (the scan-sliced per-layer weights) is
     intentionally unused — global weight access is the point."""

@@ -15,6 +15,10 @@ At serve time the table is consulted per launch:
   * `ASAP_TUNING_TABLE=<path>` — env fallback, loaded lazily once;
   * no table / no entry → the `_pick_blocks` heuristic, unchanged.
 
+A table records where it was swept (`meta.platform`, `meta.device_kind`,
+`meta.interpret`).  Installing one on a TPU run requires a table swept on
+that same TPU kind: interpret-mode CPU timings say nothing about the chip.
+
 The lookup key is fully determined by the launch's jit cache key (shapes +
 dtype), so a table hit maps each cache key to ONE blocking deterministically —
 tuned launches retain the zero-steady-state-retrace property (pinned by
@@ -105,10 +109,27 @@ _active: Optional[TuningTable] = None  # guarded_by: _table_lock
 _env_checked = False  # guarded_by: _table_lock
 
 
+def check_provenance(table: TuningTable) -> None:
+    """Refuse, on a TPU run, a table that was not swept on this TPU kind."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return
+    kind = jax.devices()[0].device_kind
+    where = (table.meta.get("platform"), table.meta.get("device_kind"))
+    if where != ("tpu", kind) or table.meta.get("interpret", True):
+        raise ValueError(
+            f"tuning table swept on {where[0]!r}/{where[1]!r} (interpret="
+            f"{table.meta.get('interpret')!r}) cannot tune kernels on "
+            f"{kind!r} — re-run benchmarks/tune_superkernel.py on the chip")
+
+
 def set_table(table: Optional[TuningTable]) -> None:
     """Install (or clear, with None) the process-wide active table.  Called
     at engine construction, BEFORE worker threads trace any kernels."""
     global _active, _env_checked
+    if table is not None:
+        check_provenance(table)
     with _table_lock:
         _active = table
         _env_checked = True  # explicit install wins over the env fallback
@@ -125,6 +146,7 @@ def get_table() -> Optional[TuningTable]:
             path = os.environ.get(ENV_VAR)
             if path:
                 _active = TuningTable.load(path)
+                check_provenance(_active)
         return _active
 
 

@@ -22,8 +22,7 @@ from typing import Optional
 import jax
 
 from repro.configs import SHAPES, cells, cell_supported, get_config
-from repro.launch.mesh import (jit_shardings, make_production_mesh,
-                               mesh_context)
+from repro.launch.mesh import make_production_mesh
 from repro.launch import sharding as SH
 from repro.launch.hlo_analysis import analyze
 from repro.launch.steps import TrainState, build_train_step
@@ -138,16 +137,14 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         return rec
     try:
         cfg, fn, args, in_sh, meta = build_cell(arch, shape_name, mesh, opts)
-        with mesh_context(mesh):
-            jitted = jax.jit(fn, in_shardings=jit_shardings(mesh, in_sh))
+        with jax.set_mesh(mesh):
+            jitted = jax.jit(fn, in_shardings=in_sh)
             lowered = jitted.lower(*args)
             t_lower = time.time() - t0
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
             mem = compiled.memory_analysis()
             cost = compiled.cost_analysis()
-            if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-                cost = cost[0] if cost else {}
             hlo = compiled.as_text()
         # Static HLO analysis with loop-trip multipliers (cost_analysis counts
         # while bodies once — verified; see launch/hlo_analysis.py).

@@ -16,8 +16,7 @@ import jax
 
 from repro.launch.dryrun import PEAK_FLOPS, HBM_BW, LINK_BW, run_cell
 from repro.launch.hlo_analysis import analyze
-from repro.launch.mesh import (jit_shardings, make_production_mesh,
-                               mesh_context)
+from repro.launch.mesh import make_production_mesh
 
 
 def main():
@@ -72,8 +71,8 @@ def main():
         mesh = make_production_mesh(multi_pod=args.multi_pod)
         cfg, fn, cell_args, in_sh, meta = build_cell(args.arch, args.shape,
                                                      mesh, opts)
-        with mesh_context(mesh):
-            hlo = jax.jit(fn, in_shardings=jit_shardings(mesh, in_sh)).lower(
+        with jax.set_mesh(mesh):
+            hlo = jax.jit(fn, in_shardings=in_sh).lower(
                 *cell_args).compile().as_text()
         hc = analyze(hlo, breakdown=True, top_k=8)
         print("\n-- top dots (flops) --")

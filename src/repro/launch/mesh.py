@@ -7,19 +7,13 @@ Multi-pod: 2 x 16 x 16 = 512 chips with a leading pure-DP "pod" axis.
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n: int) -> dict:
-    """jax.sharding.AxisType only exists on newer jax; Auto is the default
-    behavior there anyway, so older toolchains simply omit the kwarg."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 2, model: int = 2):
@@ -28,27 +22,7 @@ def make_host_mesh(data: int = 2, model: int = 2):
     data = min(data, n)
     model = max(1, min(model, n // data))
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_type_kwargs(2))
-
-
-def mesh_context(mesh):
-    """Context manager activating `mesh` for jit(in_shardings=PartitionSpec).
-
-    Newer jax spells it jax.set_mesh(mesh); the pinned 0.4.x toolchain uses
-    the legacy `with mesh:` context. Both return a context manager."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
-def jit_shardings(mesh, spec_tree):
-    """in_shardings compat: newer jax accepts PartitionSpec trees under
-    set_mesh; 0.4.x jit only takes Shardings, so wrap each spec leaf in a
-    NamedSharding. None subtrees pass through (jit infers those)."""
-    if getattr(jax, "set_mesh", None) is not None:
-        return spec_tree
-    return jax.tree_util.tree_map(
-        lambda s: jax.sharding.NamedSharding(mesh, s)
-        if isinstance(s, jax.sharding.PartitionSpec) else s, spec_tree)
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def batch_axes(mesh) -> tuple:

@@ -22,6 +22,12 @@ over either runtime:
   PYTHONPATH=src python -m repro.launch.serve --engine executor --requests 8 --rps 4
   PYTHONPATH=src python -m repro.launch.serve --engine sim --rps 4
 
+Model: the executor engine serves the reduced qwen3-MoE smoke model (3
+layers, d_model 128, 8 experts top-2), built by `model_config()` /
+`init_params`.  `chip_smoke.py` builds qwen3_moe_235b_a22b at its published
+widths through the same `model_config(arch, layers)` / `init_params` /
+`configure_compile_cache`.
+
 Geometry is shared by both engines: --dp-groups D attention groups and
 --moe-devices E MoE devices (defaults: 2x4 executor smoke, 4x16 sim
 paper-faithful).  --time-scale compresses the executor's wall-clock replay
@@ -77,8 +83,10 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 import time
+from typing import Optional
 
 import jax
 import numpy as np
@@ -98,6 +106,7 @@ from repro.core.simulator import SimConfig
 from repro.core.trace import Request, TraceClock, TraceConfig, \
     generate_requests, sample_lengths, sample_out_len
 from repro.kernels.super_gmm import tuning
+from repro.models.common import ModelConfig
 from repro.models.lm import init_lm_params
 
 
@@ -105,17 +114,66 @@ def _fmt_decomp(d):
     return " ".join(f"{k}={v * 1000:.0f}ms" for k, v in d.items())
 
 
+# ---------------------------------------------------------------------------
+# Model construction shared by the CLI runs and chip_smoke.py
+# ---------------------------------------------------------------------------
+
+REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                         "..", ".."))
+
+
+def configure_compile_cache() -> str:
+    """JAX's persistent compile cache for an entry point: the directory in
+    `JAX_COMPILATION_CACHE_DIR` when it is set (JAX reads it itself), else
+    the fixed `<repo>/.jax_cache` (gitignored) — a fixed path, because the
+    path is part of every cache key.  Entry points call this once; nothing
+    sets it at import."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def model_config(arch: str = "smoke",
+                 layers: Optional[int] = None) -> ModelConfig:
+    """The served model.  "smoke" is the reduced qwen3-MoE model (3 layers,
+    d_model 128, 8 experts top-2, float32); any registered MoE arch serves
+    its published widths, with depth cut to `layers` when given."""
+    if arch == "smoke":
+        cfg = get_config("qwen3_moe_235b_a22b").smoke().replace(
+            num_layers=3, num_experts=8, top_k=2)
+    else:
+        cfg = get_config(arch)
+        assert cfg.family == "moe", f"{arch} is not an MoE model"
+    if layers is not None:
+        cfg = cfg.replace(num_layers=layers)
+    return cfg
+
+
+def init_params(cfg: ModelConfig, seed: int):
+    """Random weights from `seed`, initialized on the default device in one
+    jitted program (no host copy of a multi-GB model)."""
+    return jax.jit(init_lm_params, static_argnums=1)(
+        jax.random.PRNGKey(seed), cfg)
+
+
+def smoke_batcher() -> LengthAwareBatcher:
+    """The CLI runs' batcher: batches of up to 128 tokens."""
+    return LengthAwareBatcher(inflection=64, max_tokens=128,
+                              exclusive_cutoff=10_000, max_wait=0.05)
+
+
 def run_executor(args) -> int:
-    cfg = get_config("qwen3_moe_235b_a22b").smoke().replace(
-        num_layers=3, num_experts=8, top_k=2)
-    key = jax.random.PRNGKey(args.seed)
-    params = init_lm_params(key, cfg)
+    cfg = model_config()
+    params = init_params(cfg, args.seed)
     D = args.dp_groups if args.dp_groups is not None else 2
     E = args.moe_devices if args.moe_devices is not None else 4
     placement = Placement.parse(args.placement,
                                 replicate_hot=args.replicate_hot)
     print(f"disaggregated executor engine: D={D} attention groups, E={E} MoE "
-          f"devices, {cfg.num_layers}L x {cfg.num_experts}e model  "
+          f"devices, {cfg.num_layers}L x {cfg.num_experts}e "
+          f"d_model={cfg.d_model} model  "
           f"[moe_path={args.moe_path} kernel={args.moe_kernel} "
           f"placement={placement.policy}"
           + (f"(hot={placement.replicate_hot})" if placement.replicate_hot
@@ -161,9 +219,7 @@ def run_executor(args) -> int:
         print(f"fault plan armed (supervised failover): "
               f"{[ev.to_dict() for ev in plan.events]}")
     engine = ExecutorEngine(
-        ex, clock=TraceClock(speed=args.time_scale),
-        batcher=LengthAwareBatcher(inflection=64, max_tokens=128,
-                                   exclusive_cutoff=10_000, max_wait=0.05),
+        ex, clock=TraceClock(speed=args.time_scale), batcher=smoke_batcher(),
         rebalance_interval=args.rebalance_interval,
         rebalance_threshold=args.rebalance_threshold,
         rebalance_policy=args.rebalance_policy,
@@ -224,7 +280,8 @@ def run_executor(args) -> int:
           f"({', '.join(f'{fr[e]:.3f}' for e in hot)})")
     if st.migrations:
         print(f"live re-placement: {st.migrations} migration(s), "
-              f"{st.migrated_bytes / 1e6:.2f} MB of expert weights moved, "
+              f"{st.migrated_bytes / 1e6:.2f} MB of expert weights a "
+              f"cross-chip placement would move (ids swapped in place), "
               f"now serving placement={st.placement_policy}")
     if st.statuses:
         print("request statuses: "
@@ -273,6 +330,15 @@ def run_executor(args) -> int:
     if missing:  # CI smoke gate: per-request results must all exist
         print(f"ERROR: missing results for rids {missing}", file=sys.stderr)
         return 1
+    # without an injected fault or a lifecycle limit, every request must
+    # end ok on its first try: a retry or failover there hides a real error
+    if plan is None and args.request_deadline is None \
+            and args.max_queue is None and args.hedge_factor is None:
+        bad = [r.rid for r in results if r.status != "ok" or r.retries]
+        if bad or st.failovers:
+            print(f"ERROR: rids {bad} not ok on the first try, "
+                  f"{st.failovers} failover(s)", file=sys.stderr)
+            return 1
     return 0
 
 
@@ -419,10 +485,8 @@ def run_pd(args) -> int:
         return _pd_gate(results, reqs, orch.kv_log, args.colocated)
 
     # --- real executor backend -------------------------------------------
-    cfg = get_config("qwen3_moe_235b_a22b").smoke().replace(
-        num_layers=3, num_experts=8, top_k=2)
-    key = jax.random.PRNGKey(args.seed)
-    params = init_lm_params(key, cfg)
+    cfg = model_config()
+    params = init_params(cfg, args.seed)
     D = args.dp_groups if args.dp_groups is not None else 2
     E = args.moe_devices if args.moe_devices is not None else 4
     slots = args.decode_width if args.decode_width is not None else 4
@@ -452,10 +516,8 @@ def run_pd(args) -> int:
                                moe_batch_window=args.moe_batch_window,
                                moe_batch_max_tokens=args.moe_batch_max_tokens)
     clock = TraceClock(speed=args.time_scale)
-    pre = ExecutorEngine(
-        ex, clock=clock, keep_kv=True,
-        batcher=LengthAwareBatcher(inflection=64, max_tokens=128,
-                                   exclusive_cutoff=10_000, max_wait=0.05))
+    pre = ExecutorEngine(ex, clock=clock, keep_kv=True,
+                         batcher=smoke_batcher())
     rt = DecodeExecutor(params, cfg, slots=slots, max_len=max_len,
                         clock=clock.now)
     dec = ExecDecodeEngine(rt)
@@ -729,8 +791,11 @@ def main():
                 ap.error(f"{flag} is not supported with --mode pd (the "
                          f"disaggregated path runs the plain prefill "
                          f"lifecycle; run those knobs without --mode pd)")
+        if args.engine == "executor":
+            configure_compile_cache()
         sys.exit(run_pd(args))
     if args.engine == "executor":
+        configure_compile_cache()
         sys.exit(run_executor(args))
     sys.exit(run_simulation(args))
 

@@ -17,7 +17,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_config
 from repro.data.pipeline import pipeline_for
-from repro.launch.mesh import make_host_mesh, jit_shardings, mesh_context
+from repro.launch.mesh import make_host_mesh
 from repro.launch import sharding as SH
 from repro.launch.steps import TrainState, build_train_step
 from repro.models.api import build_api
@@ -73,9 +73,8 @@ def main():
                                       args.batch, "train")
             return b
 
-    with mesh_context(mesh):
-        jitted = jax.jit(step_fn,
-                         in_shardings=jit_shardings(mesh, (sspecs, None)))
+    with jax.set_mesh(mesh):
+        jitted = jax.jit(step_fn, in_shardings=(sspecs, None))
 
         def on_step(step, metrics):
             if step % 5 == 0 or step == 1:

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.interpret import resolve_interpret
 from repro.kernels.super_gmm.ops import _pick_blocks, make_super_kernel_gmm, \
     super_moe_ffn
 from repro.kernels.super_gmm.ref import super_gmm_ref, super_moe_ffn_ref
@@ -15,6 +16,39 @@ from repro.kernels.dispatch_combine.ops import (kernel_moe_combine,
                                                 kernel_moe_dispatch)
 from repro.models.common import ModelConfig
 from repro.models.moe import moe_combine, moe_dispatch, router_topk
+
+
+# ------------------------------------------------------- interpret policy
+
+@pytest.mark.parametrize("backend,flag,want", [
+    ("cpu", None, True), ("cpu", False, False), ("cpu", True, True),
+    ("tpu", None, False), ("tpu", False, False),
+    ("tpu", True, ValueError), ("gpu", None, RuntimeError)])
+def test_resolve_interpret(monkeypatch, backend, flag, want):
+    """One decision for every Pallas call: interpreted on the CPU, compiled
+    on a TPU, never interpreted on a TPU, and no other backend."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if isinstance(want, bool):
+        assert resolve_interpret(flag) is want
+    else:
+        with pytest.raises(want):
+            resolve_interpret(flag)
+
+
+def test_super_gmm_expert_ids_index_the_shared_stack():
+    """Buffers addressed by expert id into a larger [L, n, K, N] stack match
+    the oracle — how an MoE device serves its experts with no copy."""
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    w = jax.random.normal(ks[0], (2, 8, 16, 32), jnp.float32)
+    x = jax.random.normal(ks[1], (3, 8, 16), jnp.float32)
+    ids = jnp.asarray([6, 1, 4], jnp.int32)
+    lid = jnp.asarray([1], jnp.int32)
+    out = super_gmm(lid, w, x, ids, block_c=8, block_n=16, block_k=8)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(super_gmm_ref(lid, w, x, ids)),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(out[1]), np.asarray(x[1] @ w[1, 1]), rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------- super gmm

@@ -9,7 +9,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCHS, EXTRA_ARCHS, get_config
 from repro.launch import sharding as SH
-from repro.launch.mesh import jit_shardings, make_host_mesh, mesh_context
+from repro.launch.mesh import make_host_mesh
 from repro.models.api import build_api
 
 
@@ -79,9 +79,8 @@ def test_sharded_train_step_lowers_on_host_mesh():
         lambda: api.make_batch(jax.random.PRNGKey(0), 32, 4, "train"))
     bspecs = SH.batch_specs(batch_sds, mesh)
     fn = build_train_step(api, opt)
-    with mesh_context(mesh):
-        lowered = jax.jit(fn, in_shardings=jit_shardings(
-            mesh, (sspecs, bspecs))).lower(
+    with jax.set_mesh(mesh):
+        lowered = jax.jit(fn, in_shardings=(sspecs, bspecs)).lower(
             state_sds, batch_sds)
         assert lowered is not None
 

@@ -141,3 +141,29 @@ def test_sweep_harness_quick_produces_loadable_table(tmp_path):
     for key, C, up, _, down, _ in r["rows"]:
         got = loaded.lookup(key, int(C))
         assert got is not None and (str(got[0]), str(got[1])) == (up, down)
+
+
+@pytest.mark.parametrize("meta,refused", [
+    ({"platform": "cpu", "interpret": True}, True),
+    ({"platform": "tpu", "device_kind": "TPU v4", "interpret": False}, True),
+    ({}, True),
+    ({"platform": "tpu", "device_kind": "TPU v5 lite", "interpret": False},
+     False),
+])
+def test_tpu_run_refuses_a_table_swept_elsewhere(monkeypatch, meta, refused):
+    """A table swept in interpret mode on the CPU (or on another TPU kind)
+    never tunes kernels on a TPU run; on the CPU any table installs."""
+    t = tuning.TuningTable(meta=meta)
+    tuning.set_table(t)  # CPU backend: accepted whatever its provenance
+
+    class _Dev:
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    if refused:
+        with pytest.raises(ValueError, match="re-run"):
+            tuning.set_table(t)
+    else:
+        tuning.set_table(t)
+        assert tuning.get_table() is t
