@@ -1,0 +1,7 @@
+# The benchmark's own tests: `chipbench` is imported from the checkout root.
+import os
+import sys
+
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
