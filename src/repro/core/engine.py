@@ -54,6 +54,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.cost_model import Deployment, Placement, resample_fractions
 from repro.core.executor import BatchJob, DisaggregatedExecutor
 from repro.core.faults import FaultPlan
@@ -736,7 +737,8 @@ class ExecutorEngine(ServingEngine):
                         if self._arrivals else None
                     flush_due = self.batcher.next_flush_due(now)
                 for b in emitted:
-                    self._launch(b)
+                    with spans.span("asap.engine.launch"):
+                        self._launch(b)
                 targets = [t for t in (next_arrival, flush_due)
                            if t is not None]
                 if targets:
@@ -804,11 +806,15 @@ class ExecutorEngine(ServingEngine):
             return
         first = None
         if self.sample_first_token and job.result is not None:
-            rows = np.arange(len(reqs))
-            pos = np.asarray(job.lengths, np.int64) - 1
-            h_last = jnp.asarray(np.asarray(job.result)[rows, pos])
-            first = np.asarray(
-                jnp.argmax(lm_head(self.ex.params, h_last, self.cfg), -1))
+            with spans.span("asap.engine.head"):
+                rows = np.arange(len(reqs))
+                pos = np.asarray(job.lengths, np.int64) - 1
+                h_last = jnp.asarray(np.asarray(job.result)[rows, pos])
+                first = np.asarray(
+                    jnp.argmax(lm_head(self.ex.params, h_last, self.cfg), -1))
+            # this runs on the group worker that served the job: its cell
+            self.ex.record_copies(job.group, h2d=h_last.nbytes,
+                                  d2h=first.nbytes)
         t_done = job.t_finished
         with self._done_cv:
             self._live_jobs = [j for j in self._live_jobs if j is not job]

@@ -87,6 +87,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.async_primitives import (AbortedError, AttnDeviceBuffer,
                                          CombinePayload, DispatchPayload,
                                          MoEDeviceBuffer)
@@ -115,9 +116,10 @@ def make_attn_step(cfg: ModelConfig, *, emit_kv: bool = False,
     With `emit_kv` (ISSUE 9) the attention part runs through
     `attention_prefill` and the step ALSO returns the layer's (k, v) cache —
     the raw material of the prefill->decode KV handoff.  Both flags are
-    Python-level, so the jit cache still keys on shapes only."""
+    Python-level, so the jit cache still keys on shapes only.  The step's
+    name is the profiler's name for its program (`jit_asap_attn_step`)."""
 
-    def step(sp, lid, h):
+    def asap_attn_step(sp, lid, h):
         on_trace()
         lp = jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, lid, 0, keepdims=False),
@@ -144,7 +146,7 @@ def make_attn_step(cfg: ModelConfig, *, emit_kv: bool = False,
                                    act_fn(cfg.act))
         return h, xf, weights, idx, out_shared, kv
 
-    return jax.jit(step)
+    return jax.jit(asap_attn_step)
 
 
 def make_moe_step(cfg: ModelConfig, *, kernel: str = "pallas",
@@ -155,12 +157,12 @@ def make_moe_step(cfg: ModelConfig, *, kernel: str = "pallas",
     is a runtime [1] scalar — ONE trace serves every layer and every device
     with the same n_e; new traces only occur for new capacity buckets."""
 
-    def step(experts, ids, lid, xb):
+    def asap_moe_step(experts, ids, lid, xb):
         on_trace()
         return super_moe_ffn(lid, experts, xb, cfg, expert_ids=ids,
                              kernel=kernel)
 
-    return jax.jit(step)
+    return jax.jit(asap_moe_step)
 
 
 @dataclasses.dataclass
@@ -395,6 +397,19 @@ class DisaggregatedExecutor:
         self._seen_buckets: List[set] = [set() for _ in range(E)]
         # guarded_by: protocol
         # (single-writer per element: same owner as bucket_hits/misses)
+        # --- host<->device copies of the served path ----------------------
+        # `.nbytes` of every array the served path moves after set-up, in
+        # the cell of the thread that moves it: cell g for attention group
+        # g (the engine's head copies run on that thread too), cell D + e
+        # for MoE device e.  Same ownership rule as moe_busy.
+        self.h2d_bytes = np.zeros(D + E)  # guarded_by: protocol
+        # (single-writer per element: group worker g / MoE worker e or the
+        # post-fence supervisor; readers tolerate a stale sum)
+        self.d2h_bytes = np.zeros(D + E)  # guarded_by: protocol
+        # (single-writer per element, as h2d_bytes)
+        self.moe_pad_rows = np.zeros(D)  # guarded_by: protocol
+        # (single-writer per element: group worker g counts the (token, k)
+        # rows it dispatched at or past their prompt's length)
 
 
     def _logev(self, *ev):
@@ -404,6 +419,12 @@ class DisaggregatedExecutor:
     def _count_trace(self, name: str):
         with self._trace_lock:  # jit tracing may run on several threads
             self.trace_counts[name] += 1
+
+    def record_copies(self, cell: int, h2d: int = 0, d2h: int = 0):
+        """Count bytes moved host->device and device->host in `cell` (see
+        h2d_bytes).  The caller is the cell's single writer."""
+        self.h2d_bytes[cell] += h2d  # race-ok: single-writer (the cell's own thread)
+        self.d2h_bytes[cell] += d2h  # race-ok: single-writer (the cell's own thread)
 
     # ------------------------------------------------- placement derivation
     def _dispatch_lookups(self, table, dev_experts):
@@ -535,6 +556,16 @@ class DisaggregatedExecutor:
             self.moe_bufs[e].dispatch_send(g, j, p, stop=self.stop)
         self._logev("dispatch", g, slot, layer, e, int(len(t_rows)))
 
+    def _count_dispatch(self, g: int, xf_np: np.ndarray,
+                        valid: Optional[np.ndarray]):
+        """Group worker g's counters for one batch-layer's dispatch: the
+        D2H of the payload source and the (token, k) rows of pad
+        positions."""
+        self.record_copies(g, d2h=xf_np.nbytes)
+        if valid is not None:
+            pad = (valid.size - np.count_nonzero(valid)) * self.cfg.top_k
+            self.moe_pad_rows[g] += pad  # race-ok: single-writer (group worker g)
+
     def _dispatch(self, g: int, slot: int, layer: int, xf, idx,
                   valid: Optional[np.ndarray] = None):
         """async-dispatch-send: ONE stable argsort over (device, expert)
@@ -542,6 +573,7 @@ class DisaggregatedExecutor:
         self._gate_enter()
         try:
             xf_np = np.asarray(xf)
+            self._count_dispatch(g, xf_np, valid)
             flat_e, flat_t, flat_k, dev = self._flat_routing(np.asarray(idx),
                                                              layer, valid)
             order = np.argsort(dev * max(self.cfg.num_experts, 1) + flat_e,
@@ -565,6 +597,7 @@ class DisaggregatedExecutor:
         self._gate_enter()
         try:
             xf_np = np.asarray(xf)
+            self._count_dispatch(g, xf_np, valid)
             flat_e, flat_t, flat_k, dev = self._flat_routing(np.asarray(idx),
                                                              layer, valid)
             for e in range(self.E):
@@ -586,11 +619,11 @@ class DisaggregatedExecutor:
         accumulator from the shared-expert output (or zeros), so neither
         leaves the compiler a trailing add to reorder."""
 
-        def step(acc0, outs, t, w):
+        def asap_combine_step(acc0, outs, t, w):
             self._count_trace("combine")
             return acc0.at[t].add(outs * w[:, None])
 
-        return jax.jit(step)
+        return jax.jit(asap_combine_step)
 
     def _combine(self, g: int, slot: int, h, xf, weights, shared):
         """async-combine-recv + weighted accumulation (token-order restore).
@@ -604,13 +637,26 @@ class DisaggregatedExecutor:
         than the bound) surfaces as TimeoutError and the group worker
         replays the batch through the retry path instead of wedging for the
         240s protocol default (ISSUE 8)."""
-        payloads = self.attn_bufs[g][slot].combine_recv(
-            timeout=self.region_timeout, stop=self.stop)
+        with spans.span("asap.group.combine_wait"):
+            payloads = self.attn_bufs[g][slot].combine_recv(
+                timeout=self.region_timeout, stop=self.stop)
+        with spans.span("asap.group.combine"):
+            return self._accumulate(g, slot, payloads, h, xf, weights,
+                                    shared)
+
+    def _accumulate(self, g: int, slot: int, payloads, h, xf, weights,
+                    shared):
+        """The combine's weighted accumulation of one batch-layer's expert
+        outputs, added to the residual `h`."""
         Tn, d = xf.shape
         layer = None
+        h2d = d2h = 0
         if self.combine_path == "host":
-            acc = np.zeros((Tn, d), np.float32) if shared is None \
-                else np.array(shared, np.float32)
+            if shared is None:
+                acc = np.zeros((Tn, d), np.float32)
+            else:
+                acc = np.array(shared, np.float32)
+                d2h += shared.nbytes
             for p in payloads:
                 if p.outputs is None or len(p.token_ids) == 0:
                     continue
@@ -632,14 +678,18 @@ class DisaggregatedExecutor:
             acc0 = jnp.zeros((Tn, d), jnp.float32) if shared is None \
                 else shared.astype(jnp.float32)
             if outs:
-                acc = np.asarray(self._combine_step(
-                    acc0, jnp.asarray(np.concatenate(outs, 0)),
-                    jnp.asarray(np.concatenate(ts, 0)),
-                    jnp.asarray(np.concatenate(ws, 0).astype(np.float32))))
+                args = (jnp.asarray(np.concatenate(outs, 0)),
+                        jnp.asarray(np.concatenate(ts, 0)),
+                        jnp.asarray(np.concatenate(ws, 0).astype(np.float32)))
+                h2d += sum(a.nbytes for a in args)
+                acc = np.asarray(self._combine_step(acc0, *args))
             else:
                 acc = np.asarray(acc0)
+            d2h += acc.nbytes
         B, S, _ = h.shape
-        y = jnp.asarray(acc.astype(np.float32)).astype(h.dtype)
+        y = jnp.asarray(acc.astype(np.float32))
+        self.record_copies(g, h2d=h2d + y.nbytes, d2h=d2h)
+        y = y.astype(h.dtype)
         self._logev("combine", g, slot, layer)
         return h + y.reshape(B, S, d)
 
@@ -711,14 +761,25 @@ class DisaggregatedExecutor:
             seen.add(C)
             self.bucket_misses[e] += 1  # race-ok: single-writer
 
+    @staticmethod
+    def _join_rows(rows):
+        """(tokens, token_ids, expert_ids) of one taken region: its T
+        payload rows joined."""
+        with spans.span("asap.moe.pack"):
+            return (np.concatenate([r.tokens for r in rows], 0),
+                    np.concatenate([r.token_ids for r in rows], 0),
+                    np.concatenate([r.expert_ids for r in rows], 0))
+
     def _expert_ffn_fused(self, e: int, layer: int, tokens: np.ndarray,
                           eids: np.ndarray) -> np.ndarray:
         """Capacity-buffer pack -> one super-kernel call -> unpack."""
         n_e = len(self.dev_experts[e])
-        xb, order, slots, C = pack_capacity(tokens, eids, n_e)
+        with spans.span("asap.moe.pack"):
+            xb, order, slots, C = pack_capacity(tokens, eids, n_e)
         self._record_launch(e, C, 1, len(tokens))
-        yb = self._moe_launch(e, layer, xb)
-        return unpack_capacity(np.asarray(yb), order, slots, len(tokens))
+        yb = self._launch_and_fetch(e, layer, xb)
+        with spans.span("asap.moe.unpack"):
+            return unpack_capacity(yb, order, slots, len(tokens))
 
     def _expert_ffn_fused_multi(self, e: int, layer: int, token_list,
                                 eid_list) -> List[np.ndarray]:
@@ -728,11 +789,25 @@ class DisaggregatedExecutor:
         provenance comes back through `bounds`, so each region's outputs
         scatter to its OWN combine path."""
         n_e = len(self.dev_experts[e])
-        xb, order, slots, C, bounds = pack_capacity_multi(
-            token_list, eid_list, n_e)
+        with spans.span("asap.moe.pack"):
+            xb, order, slots, C, bounds = pack_capacity_multi(
+                token_list, eid_list, n_e)
         self._record_launch(e, C, len(token_list), int(bounds[-1]))
-        yb = self._moe_launch(e, layer, xb)
-        return unpack_capacity_multi(np.asarray(yb), order, slots, bounds)
+        yb = self._launch_and_fetch(e, layer, xb)
+        with spans.span("asap.moe.unpack"):
+            return unpack_capacity_multi(yb, order, slots, bounds)
+
+    def _launch_and_fetch(self, e: int, layer: int,
+                          xb: np.ndarray) -> np.ndarray:
+        """A served super-kernel launch and the fetch of its result, with
+        the copies counted in device e's cell: xb and the [1] int32 layer
+        id in, the result out."""
+        with spans.span("asap.moe.launch"):
+            yb = self._moe_launch(e, layer, xb)
+        with spans.span("asap.moe.fetch"):
+            y = np.asarray(yb)
+        self.record_copies(self.D + e, h2d=xb.nbytes + 4, d2h=y.nbytes)
+        return y
 
     def _expert_ffn_eager(self, e: int, layer: int, tokens: np.ndarray,
                           eids: np.ndarray) -> np.ndarray:
@@ -854,9 +929,7 @@ class DisaggregatedExecutor:
         prep = []  # (region, layer, slot, tokens, token_ids, eids)
         for i, rows in entries:
             prep.append((i, rows[0].layer, rows[0].slot,
-                         np.concatenate([r.tokens for r in rows], 0),
-                         np.concatenate([r.token_ids for r in rows], 0),
-                         np.concatenate([r.expert_ids for r in rows], 0)))
+                         *self._join_rows(rows)))
         outs: Dict[int, Optional[np.ndarray]] = {}
         by_layer: Dict[int, List[int]] = {}
         for idx, p in enumerate(prep):
@@ -892,10 +965,11 @@ class DisaggregatedExecutor:
             # batch-layer's segment
             if self._moe_gen[e] != gen:
                 continue
-            self.attn_bufs[i][slot].combine_send(
-                e, CombinePayload(layer=layer, token_ids=token_ids,
-                                  expert_ids=eids, outputs=outs[idx]),
-                stop=self.stop)
+            with spans.span("asap.moe.combine_send"):
+                self.attn_bufs[i][slot].combine_send(
+                    e, CombinePayload(layer=layer, token_ids=token_ids,
+                                      expert_ids=eids, outputs=outs[idx]),
+                    stop=self.stop)
         self._moe_active[e] = False  # race-ok: single-writer (worker e); the batch's combines happened-before
 
     def _moe_worker(self, e: int, gen: int = 0):
@@ -957,9 +1031,7 @@ class DisaggregatedExecutor:
                 i, rows = got
                 layer = rows[0].layer
                 slot = rows[0].slot
-                tokens = np.concatenate([r.tokens for r in rows], 0)
-                token_ids = np.concatenate([r.token_ids for r in rows], 0)
-                eids = np.concatenate([r.expert_ids for r in rows], 0)
+                tokens, token_ids, eids = self._join_rows(rows)
                 if len(tokens):
                     # layer-oblivious: `layer` is runtime data indexing the
                     # resident all-layer weight stack (super-kernel semantics)
@@ -986,10 +1058,11 @@ class DisaggregatedExecutor:
                 if self._moe_gen[e] != gen:
                     self._moe_active[e] = False  # race-ok: single-writer semantics transferred back; worker exits next loop
                     continue
-                self.attn_bufs[i][slot].combine_send(
-                    e, CombinePayload(layer=layer, token_ids=token_ids,
-                                      expert_ids=eids, outputs=out),
-                    stop=self.stop)
+                with spans.span("asap.moe.combine_send"):
+                    self.attn_bufs[i][slot].combine_send(
+                        e, CombinePayload(layer=layer, token_ids=token_ids,
+                                          expert_ids=eids, outputs=out),
+                        stop=self.stop)
                 self._moe_active[e] = False  # race-ok: single-writer (worker e); combine_send above happened-before
         except AbortedError:
             return  # stop observed inside a buffer wait (shutdown/panic)
@@ -1047,6 +1120,13 @@ class DisaggregatedExecutor:
                     return None
                 self._jobq_cv.wait(wait)
 
+    def _embed(self, g: int, job: BatchJob):
+        """The job's tokens to the device and through the embedding."""
+        with spans.span("asap.group.embed"):
+            tokens = jnp.asarray(job.tokens)
+            self.record_copies(g, h2d=tokens.nbytes)
+            return embed_tokens(self.params, tokens, None, self.cfg)
+
     def _group_worker(self, g: int):
         """Persistent serving loop of one attention DP group (ISSUE 4): pull
         jobs from the shared admission queue into free dual-batch slots, run
@@ -1075,8 +1155,7 @@ class DisaggregatedExecutor:
                     if job.lengths is not None:
                         valid = (np.arange(tok.shape[1])[None, :]
                                  < np.asarray(job.lengths)[:, None]).reshape(-1)
-                    h = embed_tokens(self.params, jnp.asarray(job.tokens),
-                                     None, self.cfg)
+                    h = self._embed(g, job)
                     active.append({"job": job, "h": h, "layer": 0,
                                    "phase": "attn", "slot": free_slots.pop(0),
                                    "ctx": None, "seq": 0, "valid": valid,
@@ -1088,22 +1167,30 @@ class DisaggregatedExecutor:
                     if st["phase"] != "attn":
                         continue
                     t0 = self.clock()
-                    if fused:
-                        h, xf, w, idx, shared, kv = self._attn_step(
-                            jnp.asarray(st["layer"], jnp.int32), st["h"])
-                        w, idx = np.asarray(w), np.asarray(idx)
-                        if kv is not None:  # emit_kv: per-layer KV handoff
-                            st["kv"].append((np.asarray(kv[0]),
-                                             np.asarray(kv[1])))
-                    else:
-                        h, xf, w, idx, shared = self._attn_part(
-                            self._layer_params(st["layer"]), st["h"])
+                    with spans.span("asap.group.attn"):
+                        if fused:
+                            lid = jnp.asarray(st["layer"], jnp.int32)
+                            h, xf, w, idx, shared, kv = self._attn_step(
+                                lid, st["h"])
+                            w, idx = np.asarray(w), np.asarray(idx)
+                            if kv is not None:  # emit_kv: per-layer KV handoff
+                                kv = (np.asarray(kv[0]), np.asarray(kv[1]))
+                                st["kv"].append(kv)
+                            self.record_copies(
+                                g, h2d=lid.nbytes,
+                                d2h=sum(a.nbytes for a in kv or ()))
+                        else:
+                            h, xf, w, idx, shared = self._attn_part(
+                                self._layer_params(st["layer"]), st["h"])
+                    self.record_copies(g, d2h=w.nbytes + idx.nbytes)
                     dt = self.clock() - t0
                     st["job"].kernel_time += dt
                     self.group_busy[g] += dt  # race-ok: single-writer (group worker g accumulates its own cell)
                     st["h"] = h
                     st["ctx"] = (xf, w, shared)
-                    dispatch(g, st["slot"], st["layer"], xf, idx, st["valid"])
+                    with spans.span("asap.group.dispatch"):
+                        dispatch(g, st["slot"], st["layer"], xf, idx,
+                                 st["valid"])
                     st["phase"] = "wait"
                     st["seq"] = seq = seq + 1
                 # block on the oldest outstanding combine
@@ -1125,8 +1212,10 @@ class DisaggregatedExecutor:
                 if st["layer"] >= self.L:
                     job = st["job"]
                     t0 = self.clock()
-                    job.result = np.asarray(
-                        apply_norm(st["h"], self.params["final_norm"], self.cfg))
+                    with spans.span("asap.group.final_norm"):
+                        job.result = np.asarray(apply_norm(
+                            st["h"], self.params["final_norm"], self.cfg))
+                    self.record_copies(g, d2h=job.result.nbytes)
                     if st["kv"]:
                         job.kv = (np.stack([k for k, _ in st["kv"]]),
                                   np.stack([v for _, v in st["kv"]]))
@@ -1212,8 +1301,7 @@ class DisaggregatedExecutor:
         # capped exponential backoff (wall seconds): give an in-progress
         # failover time to land before redispatching into the same hole
         time.sleep(min(0.05 * (2 ** (job.retries - 1)), 0.5))
-        st["h"] = embed_tokens(self.params, jnp.asarray(job.tokens), None,
-                               self.cfg)
+        st["h"] = self._embed(g, job)
         st["layer"] = 0
         st["phase"] = "attn"
         st["ctx"] = None
@@ -1391,9 +1479,7 @@ class DisaggregatedExecutor:
         combine wins: the worker may have sent before dying)."""
         layer = rows[0].layer
         slot = rows[0].slot
-        tokens = np.concatenate([r.tokens for r in rows], 0)
-        token_ids = np.concatenate([r.token_ids for r in rows], 0)
-        eids = np.concatenate([r.expert_ids for r in rows], 0)
+        tokens, token_ids, eids = self._join_rows(rows)
         ffn = self._expert_ffn_fused if self.moe_path == "fused" \
             else self._expert_ffn_eager
         out = None
@@ -1406,10 +1492,11 @@ class DisaggregatedExecutor:
         if abuf.has_segment(e):
             return  # the dead worker's combine landed first — keep it
         try:
-            abuf.combine_send(
-                e, CombinePayload(layer=layer, token_ids=token_ids,
-                                  expert_ids=eids, outputs=out),
-                timeout=1.0, stop=self.stop)
+            with spans.span("asap.moe.combine_send"):
+                abuf.combine_send(
+                    e, CombinePayload(layer=layer, token_ids=token_ids,
+                                      expert_ids=eids, outputs=out),
+                    timeout=1.0, stop=self.stop)
         except TimeoutError:
             # segment held by a batch-layer the group has already timed out
             # and moved past — drop it; the group's replay re-covers it
