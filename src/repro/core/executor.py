@@ -59,7 +59,8 @@ not weight copies.
 
 Numerical contract (tested): pipeline output == lm_backbone(..., moe_mode=
 "dense") for the same params — asynchrony, placement and fusion must not
-change the math.
+change the math.  A job with `lengths` keeps its pad positions out of MoE
+dispatch, so the contract holds on each row's first `lengths[i]` positions.
 
 Lifecycle (ISSUE 4 api_redesign): the executor is a LONG-LIVED engine, not a
 one-shot batch call.  `ensure_started()` spawns the D group workers + E MoE
@@ -409,7 +410,8 @@ class DisaggregatedExecutor:
         # (single-writer per element, as h2d_bytes)
         self.moe_pad_rows = np.zeros(D)  # guarded_by: protocol
         # (single-writer per element: group worker g counts the (token, k)
-        # rows it dispatched at or past their prompt's length)
+        # rows at or past their prompt's length, which it kept out of
+        # dispatch)
 
 
     def _logev(self, *ev):
@@ -515,19 +517,24 @@ class DisaggregatedExecutor:
 
     def _flat_routing(self, idx: np.ndarray, layer: int = 0,
                       valid: Optional[np.ndarray] = None):
+        """(expert, token, k, device) of every (token, k) assignment that
+        enters dispatch.  Where `valid` masks the batch's positions, a pad
+        position's assignments never do: no pad row reaches a MoE device,
+        its capacity buffer or the device load `_route` balances on.
+        Padding follows the prompt and attention is causal, so a pad
+        position's MoE output could only reach other pad positions."""
         Tn, K = idx.shape
         flat_e = idx.reshape(-1)
         flat_t = np.repeat(np.arange(Tn), K)
         flat_k = np.tile(np.arange(K), Tn)
+        if valid is not None:
+            keep = np.repeat(valid, K)
+            flat_e, flat_t, flat_k = flat_e[keep], flat_t[keep], flat_k[keep]
         if self.router_stats is not None:
             # MEASURED per-expert routing stats (ROADMAP d2): every real
             # router assignment is counted before placement routing, so the
-            # collector sees expert popularity, not device load.  `valid`
-            # masks out padding rows — pad tokens still flow through
-            # dispatch/compute (the dense-reference contract covers them)
-            # but must not contaminate the measured fractions.
-            rec = flat_e if valid is None else flat_e[np.repeat(valid, K)]
-            self.router_stats.record(layer, rec)
+            # collector sees expert popularity, not device load.
+            self.router_stats.record(layer, flat_e)
         return flat_e, flat_t, flat_k, self._route(flat_e)
 
     def _send_device(self, g: int, slot: int, layer: int, e: int, xf_np,
@@ -560,7 +567,7 @@ class DisaggregatedExecutor:
                         valid: Optional[np.ndarray]):
         """Group worker g's counters for one batch-layer's dispatch: the
         D2H of the payload source and the (token, k) rows of pad
-        positions."""
+        positions, which dispatch skips."""
         self.record_copies(g, d2h=xf_np.nbytes)
         if valid is not None:
             pad = (valid.size - np.count_nonzero(valid)) * self.cfg.top_k
@@ -625,7 +632,8 @@ class DisaggregatedExecutor:
 
         return jax.jit(asap_combine_step)
 
-    def _combine(self, g: int, slot: int, h, xf, weights, shared):
+    def _combine(self, g: int, slot: int, h, xf, weights, shared,
+                 valid: Optional[np.ndarray] = None):
         """async-combine-recv + weighted accumulation (token-order restore).
 
         combine_path="segsum" (default) runs the jitted scatter-add;
@@ -642,12 +650,13 @@ class DisaggregatedExecutor:
                 timeout=self.region_timeout, stop=self.stop)
         with spans.span("asap.group.combine"):
             return self._accumulate(g, slot, payloads, h, xf, weights,
-                                    shared)
+                                    shared, valid)
 
     def _accumulate(self, g: int, slot: int, payloads, h, xf, weights,
-                    shared):
+                    shared, valid: Optional[np.ndarray] = None):
         """The combine's weighted accumulation of one batch-layer's expert
-        outputs, added to the residual `h`."""
+        outputs, added to the residual `h`.  A pad position (`valid` False)
+        was never dispatched, so it gets the shared expert's output alone."""
         Tn, d = xf.shape
         layer = None
         h2d = d2h = 0
@@ -678,6 +687,14 @@ class DisaggregatedExecutor:
             acc0 = jnp.zeros((Tn, d), jnp.float32) if shared is None \
                 else shared.astype(jnp.float32)
             if outs:
+                pad = weights.size - sum(len(t) for t in ts)
+                if pad:
+                    # the pad positions' rows were never dispatched: fill
+                    # the sum up to its prewarmed Tn·top_k rows with zero
+                    # rows of weight 0 aimed at a pad position
+                    outs.append(np.zeros((pad, d), np.float32))
+                    ts.append(np.full(pad, np.flatnonzero(~valid)[0]))
+                    ws.append(np.zeros(pad, weights.dtype))
                 args = (jnp.asarray(np.concatenate(outs, 0)),
                         jnp.asarray(np.concatenate(ts, 0)),
                         jnp.asarray(np.concatenate(ws, 0).astype(np.float32)))
@@ -1149,8 +1166,8 @@ class DisaggregatedExecutor:
                     if job.t_started is None:
                         job.t_started = self.clock()
                     tok = np.asarray(job.tokens)
-                    # valid-position mask: pad rows compute but don't count
-                    # toward measured router stats
+                    # valid-position mask: pad positions run attention but
+                    # never enter MoE dispatch
                     valid = None
                     if job.lengths is not None:
                         valid = (np.arange(tok.shape[1])[None, :]
@@ -1202,7 +1219,7 @@ class DisaggregatedExecutor:
                 t0 = self.clock()
                 try:
                     st["h"] = self._combine(g, st["slot"], st["h"], xf, w,
-                                            shared)
+                                            shared, st["valid"])
                 except TimeoutError:
                     st["job"].comm_time += self.clock() - t0
                     self._retry_or_fail(g, st, active, free_slots)
