@@ -1,6 +1,7 @@
 """One run of one cell: set-up, the measured window, the check, the metrics.
 
-    setup     weights from the seed (one jitted call), the server built and
+    setup     weights from the seed (the configuration's reference module,
+              one jitted call), the server built and
               prewarmed for the cell's own prompt shapes, one warm-up
               request per shape; `setup_s` runs from process start to here
     window    the schedule offered open-loop for `seconds`: each request is
@@ -8,7 +9,8 @@
               first token is on the host; after the close every request is
               waited for, up to DRAIN_S
     check     once the device's peak memory is read and the server freed:
-              the float32 reference over every finished prompt: how far the
+              the configuration's float32 reference module over every
+              finished prompt: how far the
               served final hidden states lie from the reference's, and how
               far below the best logit of the head (in float32, on the
               served state at the last position) the served token lies
@@ -22,12 +24,12 @@ import os
 import shutil
 import sys
 import time
+import types
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from chipbench import counts, manifest, reference, tracing, traffic
-from chipbench import weights as weights_mod
+from chipbench import counts, manifest, tracing, traffic
 
 DRAIN_S = 60.0  # a request unanswered this long after the close never came
 TRACE_DIR = os.path.join(manifest.ROOT, ".chipbench", "trace")
@@ -66,6 +68,9 @@ class Run:
     counters: Dict[str, float]  # program counters, open to last answer
     experts_per_launch: int
     reduced: Optional[Dict[str, Any]] = None  # trace reduction
+    reference: Optional[types.ModuleType] = None  # the reference module
+    weights: Any = None  # the weights it made
+    tokens: Dict[int, np.ndarray] = dataclasses.field(default_factory=dict)
 
     def finished(self) -> List[Request]:
         return [r for r in self.requests if r.done is not None]
@@ -133,11 +138,11 @@ def drive(server, arrivals: List[traffic.Arrival],
     return reqs
 
 
-def check(model: Dict[str, Any], w, reqs: List[Request],
-          tokens: Dict[int, np.ndarray], limits: Dict[str, float]
-          ) -> Dict[str, Any]:
-    """The comparison that decides `correct`: every request due in the
-    window has an answer, ok, and
+def check(ref: types.ModuleType, model: Dict[str, Any], w,
+          reqs: List[Request], tokens: Dict[int, np.ndarray],
+          limits: Dict[str, float]) -> Dict[str, Any]:
+    """The comparison that decides `correct`, against the reference module
+    `ref`: every request due in the window has an answer, ok, and
 
       hidden_err  sqrt(sum |h - h_ref|^2 / sum |h_ref|^2) over every
                   position of every answered prompt, h the final hidden
@@ -151,12 +156,12 @@ def check(model: Dict[str, Any], w, reqs: List[Request],
         if r.done is not None and r.first_token is not None \
                 and r.hidden is not None:
             h = np.asarray(r.hidden, np.float32)
-            ref = reference.hidden_states(model, w, tokens[r.rid])
-            d = np.sum((h - ref) ** 2, axis=-1)
-            nr = np.sum(ref ** 2, axis=-1)
+            h_ref = ref.hidden_states(model, w, tokens[r.rid])
+            d = np.sum((h - h_ref) ** 2, axis=-1)
+            nr = np.sum(h_ref ** 2, axis=-1)
             r.pos_err = np.sqrt(d / nr)
             r.err_sq, r.ref_sq = float(d.sum()), float(nr.sum())
-            logits = reference.head_logits(w, h[-1])
+            logits = ref.head_logits(model, w, h[-1])
             r.head_gap = float(logits.max() - logits[r.first_token])
     done = [r for r in reqs if r.err_sq is not None]
     unanswered = sum(r.done is None or r.first_token is None
@@ -189,6 +194,7 @@ def measure(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
     dev = jax.devices()[0]
     peak = counts.peaks(dev.device_kind)
     model = cell.config["model"]
+    ref = cell.reference
     arrivals = traffic.schedule(cell.traffic, seconds)
     tokens = traffic.prompt_tokens(arrivals, model["vocab_size"], seed)
     lengths = [a.length for a in arrivals]
@@ -196,7 +202,7 @@ def measure(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
          f"({len(arrivals) / seconds:.3f} req/s), prompt lengths "
          f"{min(lengths)}-{max(lengths)} (mean {np.mean(lengths):.1f})")
     t0 = time.perf_counter()
-    w = weights_mod.make(model, seed)
+    w = ref.weights(model, seed)
     jax.block_until_ready(w)
     _log(f"weights from seed {seed}: {time.perf_counter() - t0:.2f}s")
     server = system.Server(cell.config, w, lengths, log=_log)
@@ -234,7 +240,7 @@ def measure(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
     del server
 
     t0 = time.perf_counter()
-    verdict = check(model, w, reqs, tokens, cell.config["correct"])
+    verdict = check(ref, model, w, reqs, tokens, cell.config["correct"])
     _log(f"reference over {verdict['compared']} prompts: "
          f"{time.perf_counter() - t0:.2f}s")
 
@@ -244,7 +250,8 @@ def measure(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
         reduced = tracing.reduce(ev)
     run = Run(model=model, peak=peak, setup_s=setup_s, requests=reqs,
               window_s=seconds, counters=counters,
-              experts_per_launch=experts_per_launch, reduced=reduced)
+              experts_per_launch=experts_per_launch, reduced=reduced,
+              reference=ref, weights=w, tokens=tokens)
     metrics = {}
     for m in cell.metrics(trace):
         v = manifest.load_reader(m["name"], cell.root)(run)

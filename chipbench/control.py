@@ -8,7 +8,8 @@ One process on one chip.  For each seed: the weights and the schedule of
 that seed, the program served at the cell's own load for `--seconds` (as
 many requests as a run compares), and the run's own comparison
 (`bench.check`).  For the first `--control-seeds` seeds, the control goes
-through the same comparison: the reference computed in float8 e4m3 on the
+through the same comparison: the configuration's reference module computed
+in the precision below the served one (float8 e4m3 for bfloat16) on the
 same prompts, its final hidden state and first token put in place of each
 served one.  One JSON line per seed, with each compared number and the
 per-prompt readings it was taken from.
@@ -30,28 +31,27 @@ import sys  # noqa: E402
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def control_verdict(model, weights, reqs, tokens, limits):
-    """`bench.check` with the float8 control in the program's place: each
-    answered request's final hidden states are the control's, and its first
-    token the one the control's head puts first.  Returns the verdict and
-    the requests as the control answered them."""
+def control_verdict(ref, model, weights, reqs, tokens, limits):
+    """`bench.check` with the control of the reference module `ref` in the
+    program's place: each answered request's final hidden states are the
+    control's, and its first token the one the control's head puts first.
+    Returns the verdict and the requests as the control answered them."""
     import dataclasses
 
     import numpy as np
 
-    from chipbench import bench, reference
+    from chipbench import bench
 
     swapped = []
     for r in reqs:
         if r.first_token is None:
             swapped.append(dataclasses.replace(r))
             continue
-        h = reference.hidden_states(model, weights, tokens[r.rid],
-                                    control=True)
-        tok = int(np.argmax(reference.head_logits(weights, h[-1],
-                                                  control=True)))
+        h = ref.hidden_states(model, weights, tokens[r.rid], control=True)
+        tok = int(np.argmax(ref.head_logits(model, weights, h[-1],
+                                            control=True)))
         swapped.append(dataclasses.replace(r, hidden=h, first_token=tok))
-    return bench.check(model, weights, swapped, tokens, limits), swapped
+    return bench.check(ref, model, weights, swapped, tokens, limits), swapped
 
 
 def readings(v, reqs):
@@ -84,18 +84,18 @@ def main(argv=None) -> int:
     sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
     import jax
 
-    from chipbench import bench, manifest, system, traffic, weights
+    from chipbench import bench, manifest, system, traffic
 
     if jax.devices()[0].platform != "tpu":
         print("control: no TPU", file=sys.stderr)
         return 3
     cell = manifest.load_cell(args.workload, ROOT)
-    m = cell.config["model"]
+    m, ref = cell.config["model"], cell.reference
     for i, seed in enumerate(int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
         arr = traffic.schedule(cell.traffic, args.seconds)
         toks = traffic.prompt_tokens(arr, m["vocab_size"], seed)
-        w = weights.make(m, seed)
+        w = ref.weights(m, seed)
         server = system.Server(cell.config, w, [a.length for a in arr],
                                log=lambda _: None)
         reqs = bench.drive(server, arr, toks, args.seconds,
@@ -103,12 +103,12 @@ def main(argv=None) -> int:
         server.close()
         del server
         limits = cell.config["correct"]
-        v = bench.check(m, w, reqs, toks, limits)
+        v = bench.check(ref, m, w, reqs, toks, limits)
         line = {"seed": seed, "compared": v["compared"],
                 "program": readings(v, reqs)}
         if i < args.control_seeds:
-            line["control"] = readings(*control_verdict(m, w, reqs, toks,
-                                                        limits))
+            line["control"] = readings(*control_verdict(ref, m, w, reqs,
+                                                        toks, limits))
         line["seconds"] = time.perf_counter() - t0
         print(json.dumps(line), flush=True)
         del w

@@ -1,4 +1,7 @@
-"""Operations and bytes the algorithm needs, from the configuration's shapes.
+"""Chip-side arithmetic: the table of peaks, the super-GMM's operations and
+bytes from the configuration's shapes, and the least time they need.  A
+model's own FLOPs and routed rows come from its reference module
+(`chipbench/references/<name>.py`).
 
 Padding never counts: not the rows that pad a prompt to its bucket, and not
 the empty slots of a capacity buffer.  A kernel that stops computing
@@ -24,21 +27,6 @@ def peaks(device_kind: str) -> Dict[str, Any]:
         raise KeyError(f"device kind {device_kind!r} is not in "
                        f"chipbench/peaks.json ({sorted(table)})")
     return table[device_kind]
-
-
-def prompt_flops(m: Dict[str, Any], n: int) -> float:
-    """Model FLOPs of prefilling one prompt of n real tokens: per layer the
-    q/k/v/o projections, causal scores and values over n(n+1)/2 pairs, the
-    router, 6 d f for each routed (token, expert) pair; then the head at
-    the last position only."""
-    d, H, KV, hd = m["d_model"], m["num_heads"], m["num_kv_heads"], \
-        m["head_dim"]
-    proj = 2 * n * d * (H * hd + 2 * KV * hd) + 2 * n * H * hd * d
-    attn = 2 * (n * (n + 1) // 2) * H * hd * 2
-    router = 2 * n * d * m["num_experts"]
-    experts = n * m["top_k"] * 6 * d * m["expert_d_ff"]
-    return float(m["num_layers"] * (proj + attn + router + experts)
-                 + 2 * d * m["vocab_size"])
 
 
 def expert_pair_flops(m: Dict[str, Any]) -> float:

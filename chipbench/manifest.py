@@ -8,6 +8,34 @@ mix or a metric by adding files and entries, never by editing one:
             (`chipbench/configs/<config>.json`)
   traffic:  `chipbench/traffic/<mix>.json`
   metrics:  `chipbench/metrics/<metric>.py`, a module with `read(run)`
+  reference: `chipbench/references/<name>.py`, named by the configuration
+            file's optional key `"reference": "<name>"`; without the key,
+            `decoder_moe` (DEFAULT_REFERENCE)
+
+A reference module holds all that is particular to one model architecture.
+It imports nothing of the program and provides, with `m` the configuration
+file's `model` block and `w` the weights it made:
+
+  weights(m, seed)             the benchmark's weights from the seed, in the
+                               program's parameter layout and served dtype
+  hidden_states(m, w, tokens, control=False)
+                               float32 final hidden states [n, d] of one
+                               prompt, what the head reads; with `control`,
+                               computed in the precision below the served one
+  head_logits(m, w, h, control=False)
+                               float32 logits [V] of one hidden state [d]
+  prompt_flops(m, n)           model FLOPs this chip does for a prompt of n
+                               tokens
+  routed_rows(m, w, tokens)    real (token, expert) rows that the experts
+                               held on this chip serve for one prompt,
+                               counted from the module's own float32
+                               routing, never from the program's counters
+
+Each of these refuses, with a ValueError naming the key, a `model` key that
+the module does not compute, or a value at which it does not compute it.
+Every `model` key is also compared with the program's configuration
+(`system.program_config`), so what the reference computes is what the
+program runs.
 """
 from __future__ import annotations
 
@@ -16,12 +44,16 @@ import importlib.util
 import json
 import os
 import re
+import types
 from typing import Any, Dict, List
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 NAME_RE = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
 UNIT_RE = re.compile(r"[A-Za-z0-9_/%.-]{1,16}")
+
+
+DEFAULT_REFERENCE = "decoder_moe"
 
 
 class ManifestError(ValueError):
@@ -79,6 +111,7 @@ class Cell:
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
     root: str
+    reference: types.ModuleType  # the configuration's reference module
 
     def metrics(self, trace: bool) -> List[Dict[str, Any]]:
         """The metrics a run reports: end to end with --trace 0, per layer
@@ -112,13 +145,22 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     if not entry:
         raise ManifestError(f"no workload named {name!r} in BENCHMARK.json")
     w = entry[0]
+    config = load_config(man, w["config"], root)
     return Cell(name=name, chips=int(w["chips"]), config_name=w["config"],
-                config=load_config(man, w["config"], root),
+                config=config,
                 traffic_name=w["traffic"],
                 traffic=load_traffic(w["traffic"], root),
                 end_to_end=[m for m in man["end_to_end"] if _applies(m, name)],
                 per_layer=[m for m in man["per_layer"] if _applies(m, name)],
-                root=root)
+                root=root, reference=reference_for(config, root))
+
+
+def _load_module(kind: str, path: str, name: str) -> types.ModuleType:
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_{kind}_" + re.sub(r"\W", "_", name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_reader(metric: str, root: str = ROOT):
@@ -127,8 +169,25 @@ def load_reader(metric: str, root: str = ROOT):
                         check_name(metric, "metric") + ".py")
     if not os.path.exists(path):
         raise ManifestError(f"no reader for metric {metric!r} ({path})")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + re.sub(r"\W", "_", metric), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module("metric", path, metric).read
+
+
+# one module object per file, so that its jitted functions compile once
+_REFERENCES: Dict[str, types.ModuleType] = {}
+
+
+def load_reference(name: str, root: str = ROOT) -> types.ModuleType:
+    """The reference module `chipbench/references/<name>.py`."""
+    path = os.path.join(root, "chipbench", "references",
+                        check_name(name, "reference") + ".py")
+    if not os.path.exists(path):
+        raise ManifestError(f"no reference module {name!r} ({path})")
+    if path not in _REFERENCES:
+        _REFERENCES[path] = _load_module("reference", path, name)
+    return _REFERENCES[path]
+
+
+def reference_for(config: Dict[str, Any], root: str = ROOT
+                  ) -> types.ModuleType:
+    """The reference module a configuration file names, or the default."""
+    return load_reference(config.get("reference", DEFAULT_REFERENCE), root)

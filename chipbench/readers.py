@@ -25,10 +25,11 @@ def ttft_percentile(run: Run, q: float) -> Optional[float]:
 
 
 def real_pairs(run: Run) -> int:
-    """Real routed (token, expert) rows of every finished prompt."""
-    m = run.model
-    return sum(r.length for r in run.finished()) * m["top_k"] \
-        * m["num_layers"]
+    """Real routed (token, expert) rows that this chip's experts serve for
+    every finished prompt, from the reference module's own routing."""
+    return sum(run.reference.routed_rows(run.model, run.weights,
+                                         run.tokens[r.rid])
+               for r in run.finished())
 
 
 def super_gmm_roofline(run: Run) -> Optional[float]:
@@ -57,7 +58,8 @@ def mfu_ttft(run: Run) -> Optional[float]:
     done = run.finished()
     if not done:
         return None
-    flops = sum(counts.prompt_flops(run.model, r.length) for r in done)
+    flops = sum(run.reference.prompt_flops(run.model, r.length)
+                for r in done)
     return 100.0 * flops / run.peak["bf16_flops_per_s"] \
         / sum(r.ttft for r in done)
 

@@ -49,7 +49,7 @@ def main(argv=None) -> int:
     import jax
     import numpy as np
 
-    from chipbench import bench, manifest, system, traffic, weights
+    from chipbench import bench, manifest, system, traffic
 
     if jax.devices()[0].platform != "tpu":
         print("sweep: no TPU", file=sys.stderr)
@@ -67,7 +67,8 @@ def main(argv=None) -> int:
                for a in traffic.schedule(mix, args.seconds)]
         mixes.append((rate, arr, traffic.prompt_tokens(
             arr, config["model"]["vocab_size"], args.seed)))
-    w = weights.make(config["model"], args.seed)
+    w = manifest.reference_for(config, ROOT).weights(config["model"],
+                                                     args.seed)
     server = system.Server(config, w, [a.length for _, arr, _ in mixes
                                        for a in arr])
     print(f"set-up {time.perf_counter() - T_PROCESS:.1f}s", flush=True)

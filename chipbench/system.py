@@ -25,35 +25,40 @@ def pad_bucket(n: int) -> int:
     return 1 << (max(int(n), 8) - 1).bit_length()
 
 
+# what the program is held to where the configuration file's `model` block
+# states nothing: none of these departures from a plain decoder MoE
+_UNSTATED = {"num_shared_experts": 0, "tie_embeddings": False,
+             "scale_embeddings": False, "act": "silu",
+             "nonparametric_norm": False, "qkv_bias": False,
+             "logit_softcap": None, "window_size": None}
+
+
+def _plain(value: Any) -> Any:
+    """A config attribute as a file states it: a dtype by its name."""
+    return np.dtype(value).name if isinstance(value, (type, np.dtype)) \
+        else value
+
+
 def program_config(config: Dict[str, Any]):
-    """The program's ModelConfig for a configuration file, checked against
-    the file's `model` block: the file states what is run."""
+    """The program's ModelConfig for a configuration file: the program's
+    `arch` cut to the file's `num_layers`, checked key by key against the
+    file's `model` block: the file states what is run.  A `model` key that
+    the program's ModelConfig lacks is refused, since the program cannot
+    run what it states."""
     from repro.launch import serve
 
-    m = config["model"]
-    cfg = serve.model_config(config["program"]["arch"],
-                             layers=m["num_layers"])
-    got = {"num_layers": cfg.num_layers, "d_model": cfg.d_model,
-           "num_heads": cfg.num_heads, "num_kv_heads": cfg.num_kv_heads,
-           "head_dim": cfg.head_dim, "num_experts": cfg.num_experts,
-           "top_k": cfg.top_k, "expert_d_ff": cfg.expert_d_ff,
-           "vocab_size": cfg.vocab_size, "rope_theta": cfg.rope_theta,
-           "norm_eps": cfg.norm_eps, "qk_norm": cfg.qk_norm,
-           "router_renorm": cfg.router_renorm,
-           "dtype": np.dtype(cfg.dtype).name,
-           "num_shared_experts": cfg.num_shared_experts,
-           "tie_embeddings": cfg.tie_embeddings,
-           "scale_embeddings": cfg.scale_embeddings, "act": cfg.act,
-           "nonparametric_norm": cfg.nonparametric_norm,
-           "qkv_bias": cfg.qkv_bias, "logit_softcap": cfg.logit_softcap,
-           "window_size": cfg.window_size}
-    want = dict(m, num_shared_experts=0, tie_embeddings=False,
-                scale_embeddings=False, act="silu", nonparametric_norm=False,
-                qkv_bias=False, logit_softcap=None, window_size=None)
-    bad = {k: (got[k], want[k]) for k in got if got[k] != want[k]}
+    m, prog = config["model"], config["program"]
+    cfg = serve.model_config(prog["arch"], layers=m["num_layers"])
+    want = dict(_UNSTATED, **m)
+    lacking = [k for k in want if not hasattr(cfg, k)]
+    if lacking:
+        raise ValueError(f"the program's ModelConfig has no {lacking}: it "
+                         f"cannot run what the configuration file states")
+    got = {k: _plain(getattr(cfg, k)) for k in want}
+    bad = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
     if bad:
-        raise ValueError(f"the program's {config['program']['arch']} differs "
-                         f"from the configuration file (program, file): {bad}")
+        raise ValueError(f"the program's {prog['arch']} differs from the "
+                         f"configuration file (program, file): {bad}")
     return cfg
 
 

@@ -9,10 +9,11 @@ import os
 import numpy as np
 import pytest
 
-from chipbench import bench, reference, traffic, weights
+from chipbench import bench, manifest, traffic
 from chipbench.control import control_verdict
 
 HERE = os.path.dirname(__file__)
+reference = manifest.load_reference(manifest.DEFAULT_REFERENCE)
 
 
 @pytest.fixture(scope="module")
@@ -20,7 +21,7 @@ def tiny():
     with open(os.path.join(HERE, "fixtures", "tiny.json")) as f:
         config = json.load(f)
     m = config["model"]
-    return m, weights.make(m, 31337), config["correct"]
+    return m, reference.weights(m, 31337), config["correct"]
 
 
 def served(m, w, seed, n=48):
@@ -38,7 +39,7 @@ def served(m, w, seed, n=48):
         reqs.append(bench.Request(
             rid=a.rid, length=a.length, due=a.due, done=a.due + 0.1,
             status="ok", hidden=h,
-            first_token=int(np.argmax(reference.head_logits(w, h[-1])))))
+            first_token=int(np.argmax(reference.head_logits(m, w, h[-1])))))
     return reqs, toks
 
 
@@ -46,11 +47,11 @@ def served(m, w, seed, n=48):
 def test_control_fails_the_check(tiny, seed):
     m, w, limits = tiny
     reqs, toks = served(m, w, seed)
-    sound = bench.check(m, w, reqs, toks, limits)
+    sound = bench.check(reference, m, w, reqs, toks, limits)
     assert sound["correct"] is True
     assert sound["checks"]["hidden_err"]["value"] == 0.0
     assert sound["checks"]["head_gap_max"]["value"] == 0.0
-    out, _ = control_verdict(m, w, reqs, toks, limits)
+    out, _ = control_verdict(reference, m, w, reqs, toks, limits)
     assert out["correct"] is False
     assert out["compared"] == len(reqs)
     assert out["checks"]["hidden_err"]["value"] > 3 * limits["hidden_err"]
@@ -64,9 +65,9 @@ def test_reference_at_last_position_reads_every_prompt_length(tiny):
         toks = np.arange(n, dtype=np.int32) % m["vocab_size"]
         h = reference.hidden_states(m, w, toks)
         assert h.shape == (n, m["d_model"])
-        last = reference.head_logits(w, h[-1])
+        last = reference.head_logits(m, w, h[-1])
         assert np.isfinite(last).all() and last.shape == (m["vocab_size"],)
         ctl = reference.head_logits(
-            w, reference.hidden_states(m, w, toks, control=True)[-1],
+            m, w, reference.hidden_states(m, w, toks, control=True)[-1],
             control=True)
         assert 0 < np.abs(ctl - last).max() < np.abs(last).max()

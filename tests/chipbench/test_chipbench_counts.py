@@ -1,17 +1,32 @@
-"""FLOP and byte counts of both configurations against hand counts."""
+"""FLOP and byte counts of Qwen3-235B-A22B and DBRX against hand counts:
+the model's FLOPs from the default reference module, the super-GMM's work
+from `counts`."""
 import json
 import os
 
 import pytest
 
-from chipbench import counts
+from chipbench import counts, manifest
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 
+# DBRX (HF databricks/dbrx-base config.json), one layer at published widths
+DBRX = {"num_layers": 1, "d_model": 6144, "num_heads": 48, "num_kv_heads": 8,
+        "head_dim": 128, "num_experts": 16, "top_k": 4, "expert_d_ff": 10752,
+        "vocab_size": 100352, "rope_theta": 500000.0, "norm_eps": 1e-06,
+        "qk_norm": False, "router_renorm": True, "dtype": "bfloat16"}
+
 
 def model(name):
+    if name == "dbrx-132b":
+        return DBRX
     with open(os.path.join(ROOT, "chipbench", "configs", name + ".json")) as f:
         return json.load(f)["model"]
+
+
+def prompt_flops(m, n):
+    return manifest.load_reference(manifest.DEFAULT_REFERENCE).prompt_flops(
+        m, n)
 
 
 # Prompt of 1000 real tokens.  Per layer: q/k/v/o projections
@@ -37,17 +52,17 @@ DBRX_1000 = (
 ])
 def test_prompt_flops_by_hand(name, want):
     assert want == {"qwen3-235b-a22b": QWEN3_1000, "dbrx-132b": DBRX_1000}[name]
-    assert counts.prompt_flops(model(name), 1000) == want
+    assert prompt_flops(model(name), 1000) == want
 
 
 def test_prompt_flops_grow_with_the_prompt():
     m = model("qwen3-235b-a22b")
-    one = counts.prompt_flops(m, 1)
+    one = prompt_flops(m, 1)
     # a prompt of one token: projections, one attention pair, router, 8
     # experts, head
     assert one == (2 * 4096 * 9216 + 2 * 8192 * 4096 + 2 * 64 * 128 * 2
                    + 2 * 4096 * 128 + 8 * 6 * 4096 * 1536 + 2 * 4096 * 151936)
-    assert counts.prompt_flops(m, 2048) > 2 * counts.prompt_flops(m, 1024)
+    assert prompt_flops(m, 2048) > 2 * prompt_flops(m, 1024)
 
 
 @pytest.mark.parametrize("name,pairs,launches,held,flops,nbytes,bound", [

@@ -25,9 +25,41 @@ def test_the_repository_manifest_loads_whole():
         assert c["file"].startswith("chipbench/configs/")
 
 
-@pytest.mark.parametrize("name", ["qwen3-235b-a22b", "dbrx-132b"])
+def test_the_tail_is_read_per_layer_beside_the_median():
+    """The TTFT tail is a per-layer reading; the median is the steady
+    cell's end-to-end TTFT, and every per-layer metric moves it."""
+    import numpy as np
+
+    from chipbench import bench
+
+    cell = manifest.load_cell("qwen3-235b-a22b.mixed-steady", ROOT)
+    assert [m["name"] for m in cell.end_to_end] == ["setup_s", "ttft_p50_ms"]
+    tail = [m for m in cell.per_layer if m["name"] == "engine.ttft_ms_p95"]
+    assert tail and tail[0]["source"] == "host_clock"
+    assert {m["moves"] for m in cell.per_layer} == {"ttft_p50_ms"}
+    read = manifest.load_reader("engine.ttft_ms_p95", ROOT)
+    due = np.arange(48) * 1.05
+    reqs = [bench.Request(rid=i, length=128, due=float(d), done=float(d) + t)
+            for i, (d, t) in enumerate(zip(due, np.linspace(0.2, 1.6, 48)))]
+    run = bench.Run(model={}, peak={}, setup_s=1.0, requests=reqs,
+                    window_s=51.0, counters={}, experts_per_launch=1)
+    assert read(run) == pytest.approx(
+        np.percentile(np.linspace(0.2, 1.6, 48), 95) * 1e3)
+    for r in reqs[-3:]:  # three never came: the 95th lands on a miss
+        r.done = None
+    assert read(run) is None
+
+
+CONFIG_FILES = {
+    "qwen3-235b-a22b": "chipbench/configs/qwen3-235b-a22b.json",
+    # a configuration that names its own reference module
+    "tiny-own": "tests/chipbench/fixtures/tiny-own.json",
+}
+
+
+@pytest.mark.parametrize("name", sorted(CONFIG_FILES))
 def test_config_model_block_matches_its_source_keys(name):
-    with open(os.path.join(ROOT, "chipbench", "configs", name + ".json")) as f:
+    with open(os.path.join(ROOT, CONFIG_FILES[name])) as f:
         c = json.load(f)
     for key, src in c["model_from"].items():
         v = c
@@ -67,7 +99,7 @@ def test_new_cell_config_mix_and_metric_are_found_by_name(tmp_path):
                              "why": "x"})
     man["per_layer"].append({"name": "new.thing_ms", "unit": "ms",
                              "better": "lower", "source": "program_span",
-                             "layer": "engine", "moves": "ttft_p95_ms",
+                             "layer": "engine", "moves": "ttft_p50_ms",
                              "workloads": ["newmodel.short"]})
     (root / "BENCHMARK.json").write_text(json.dumps(man))
     cell = manifest.load_cell("newmodel.short", str(root))
